@@ -11,6 +11,7 @@ import time
 from benchmarks import (autotune, dist_scaling, fig1_global, fig2_constant,
                         fig3_texture, ivf_search, minibatch, quality_parity,
                         roofline, round_traffic, seed_sampling)
+from repro.compile_cache import use_compile_cache
 
 MODULES = {
     "fig1": fig1_global,
@@ -38,4 +39,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -14,6 +14,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.core import quality
 from repro.core.engine import ClusterEngine
 from repro.data.synthetic import blobs
@@ -80,4 +81,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.data.semdedup import semdedup
 from repro.data.synthetic import blobs
 
@@ -35,4 +36,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import get_config
 from repro.models.registry import get_model
 from repro.serve import Engine, ServeConfig, kvquant
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import use_compile_cache
 from repro.configs.common import ArchConfig
 from repro.data.pipeline import DataPipeline
 from repro.data.synthetic import TokenStream
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -230,7 +230,8 @@ def seed_gate(c_new: jax.Array, cache: RoundCache,
     ctr = cache.centers
     ctr_n2 = jnp.sum(ctr * ctr, axis=1)
     dot = jax.lax.dot_general(ctr, c, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST)
     d2 = jnp.maximum(ctr_n2[:, None] - 2.0 * dot + cn[None, :], 0.0)
     dc = jnp.sqrt(jnp.min(d2, axis=1))                  # nearest new centroid
     lo = jnp.maximum(dc - cache.radii, 0.0)             # min dist to tile
@@ -437,16 +438,18 @@ def assign_point_scalars(delta: jax.Array, centroids: jax.Array,
 def assign_point_prune(prev_a: jax.Array, prev_md: jax.Array,
                        prev_lb: jax.Array, delta: jax.Array,
                        thresh: jax.Array, valid: jax.Array) -> jax.Array:
-    """Per-point ASSIGNMENT prune mask for ONE tile (bn,): True where the
+    """Per-point ASSIGNMENT prune mask for ONE tile (1, bn): True where the
     point's label AND its exact ``min_d2`` provably cannot change, so the
     k-way distance recomputation short-circuits to the carried values —
-    bitwise what a fresh compute would produce. Shared verbatim by the
-    pure-JAX model and the Pallas gated kernels (the one-hot contraction
-    instead of a gather keeps it Mosaic-friendly)."""
+    bitwise what a fresh compute would produce. The per-point inputs are
+    (1, bn) rows and ``delta`` the (k, 1) movement column (the kernels'
+    layout). Shared verbatim by the pure-JAX model and the Pallas gated
+    kernels (the one-hot contraction instead of a gather keeps it
+    Mosaic-friendly)."""
     k = delta.shape[0]
-    onehot = (prev_a[:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1))
-    own_delta = jnp.sum(jnp.where(onehot, delta[None, :], 0.0), axis=1)
+    onehot = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0) == prev_a
+    own_delta = jnp.sum(jnp.where(onehot, delta, 0.0), axis=0,
+                        keepdims=True)
     ub = jnp.sqrt(prev_md)
     return valid & (own_delta == 0.0) & (prev_lb - ub >= thresh)
 

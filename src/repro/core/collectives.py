@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pvary, shard_map  # noqa: F401  (re-exported)
 from repro.core import sampling
 
 
@@ -155,8 +154,14 @@ def dist_gumbel_topl(key: jax.Array, log_w: jax.Array, l: int, axes):
         shard_key, log_w.shape, jnp.float32)
     score, local_idx = jax.lax.top_k(g, l)
     gidx = me * n_local + local_idx.astype(jnp.int32)
-    all_scores = jax.lax.all_gather(score, axes, tiled=True)
-    all_gidx = jax.lax.all_gather(gidx, axes, tiled=True)
+    # all-gather as a psum of one-hot rows: adding zeros is exact, and a
+    # psum's result is replicated by type, so the returned top-l can leave
+    # shard_map under a replicated out_spec (an all_gather's is varying)
+    slot = (jnp.arange(axis_size(axes)) == me)[:, None]
+    all_scores = jax.lax.psum(jnp.where(slot, score[None, :], 0.0),
+                              axes).reshape(-1)
+    all_gidx = jax.lax.psum(jnp.where(slot, gidx[None, :], 0),
+                            axes).reshape(-1)
     best, pos = jax.lax.top_k(all_scores, l)
     return all_gidx[pos], best
 

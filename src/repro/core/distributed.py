@@ -23,7 +23,7 @@ import jax
 
 from repro.core.collectives import (axis_index, axis_size,  # noqa: F401
                                     dist_gumbel_choice, dist_hier_choice,
-                                    dist_tiled_choice, pvary, ring_psum,
+                                    dist_tiled_choice, ring_psum,
                                     take_global)
 from repro.core.engine import (ClusterEngine, KmeansppResult, LloydResult,
                                MeshBackend, make_backend)

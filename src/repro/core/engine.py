@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import bounds, collectives, guards, sampling
+from repro.core import bounds, collectives, distance, guards, sampling
 from repro.core.bounds import BoundState, RoundCache
 from repro.core.guards import (CheckpointError, KernelFailureError,
                                PipelineError)
@@ -166,10 +166,12 @@ class AssignRound(NamedTuple):
 
 
 def pairwise_d2(x: jax.Array, c: jax.Array) -> jax.Array:
-    """Squared euclidean distances (n, d) x (k, d) -> (n, k); MXU-friendly form."""
+    """Squared euclidean distances (n, d) x (k, d) -> (n, k); MXU-friendly form
+    (fp32 dots: the TPU's default matmul precision rounds to bf16)."""
     xn = jnp.sum(x * x, axis=-1, keepdims=True)
     cn = jnp.sum(c * c, axis=-1)
-    d2 = xn - 2.0 * (x @ c.T) + cn[None, :]
+    d2 = xn - 2.0 * jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST) \
+        + cn[None, :]
     return jnp.maximum(d2, 0.0)
 
 
@@ -195,17 +197,11 @@ def _matmul_min_d2(points: jax.Array, c_new: jax.Array,
     """min over c_new of the matmul-form D^2 with cached fp32 norms — the
     fused/Pallas round math (points/centroids keep their stream dtype into
     the dot, accumulation is fp32; bitwise what the Pallas kernels compute
-    per tile)."""
-    c = c_new.astype(points.dtype)
+    per tile — the same `core.distance.tile_d2_min`)."""
     if norms is None:
         norms = bounds.point_norms(points)
-    cf = c.astype(jnp.float32)
-    cn = jnp.sum(cf * cf, axis=-1)
-    dots = jax.lax.dot_general(points, c, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    d2 = jnp.maximum(norms.astype(jnp.float32)[:, None] - 2.0 * dots
-                     + cn[None, :], 0.0)
-    return jnp.min(d2, axis=1)
+    return distance.tile_d2_min(points, c_new.astype(points.dtype),
+                                norms.astype(jnp.float32)[None, :])[0]
 
 
 def assign_blocked(points: jax.Array, centroids: jax.Array,
@@ -215,7 +211,8 @@ def assign_blocked(points: jax.Array, centroids: jax.Array,
     """Nearest centroid per point, blocked so the (n, k) distance matrix never
     materializes whole. Returns (assignment, min_d2). ``norms`` is the cached
     fp32 ``||x||^2`` — computed on the fly when absent, hoisted out of the
-    Lloyd loop by the engine."""
+    Lloyd loop by the engine. Each block runs the Pallas assignment
+    kernel's math (`core.distance.nearest` over `tile_d2`)."""
     n, d = points.shape
     pad = (-n) % block
     pts = jnp.pad(points, ((0, pad), (0, 0)))
@@ -223,19 +220,13 @@ def assign_blocked(points: jax.Array, centroids: jax.Array,
         norms = bounds.point_norms(points)
     nrm = jnp.pad(norms.astype(jnp.float32), (0, pad))
     cents = centroids.astype(points.dtype)
-    cf = cents.astype(jnp.float32)
-    cn = jnp.sum(cf * cf, axis=-1)
 
     def blk(args):
         x, xn = args
-        dots = jax.lax.dot_general(x, cents, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        d2 = jnp.maximum(xn[:, None] - 2.0 * dots + cn[None, :], 0.0)
-        a = jnp.argmin(d2, axis=1).astype(jnp.int32)
-        return a, jnp.min(d2, axis=1)
+        return distance.nearest(distance.tile_d2(x, cents, xn))
 
     a, m = jax.lax.map(blk, (pts.reshape(-1, block, d),
-                             nrm.reshape(-1, block)))
+                             nrm.reshape(-1, 1, block)))
     return a.reshape(-1)[:n], m.reshape(-1)[:n]
 
 
@@ -341,11 +332,13 @@ def _assign_tiled_model(points, centroids, norms, tile, tps=None):
         return _tile_assign(x, xn, cents, vld)
 
     a, m, part, gap, lb, tsums, tcounts = jax.lax.map(
-        blk, (pts.reshape(-1, tile, d), nrm.reshape(-1, tile),
-              valid.reshape(-1, tile)))
-    return (a.reshape(-1)[:n], m.reshape(-1)[:n], part, gap,
-            lb.reshape(-1)[:n], bounds.super_reduce(tsums, tps),
-            bounds.super_reduce(tcounts, tps))
+        blk, (pts.reshape(-1, tile, d), nrm.reshape(-1, 1, tile),
+              valid.reshape(-1, 1, tile)))
+    n_tiles = part.shape[0]
+    return (a.reshape(-1)[:n], m.reshape(-1)[:n], part.reshape(n_tiles),
+            gap.reshape(n_tiles), lb.reshape(-1)[:n],
+            bounds.super_reduce(tsums, tps),
+            bounds.super_reduce(tcounts[:, 0], tps))
 
 
 def _assign_pruned_model(points, centroids, norms, tile, state: BoundState,
@@ -368,17 +361,22 @@ def _assign_pruned_model(points, centroids, norms, tile, state: BoundState,
     pmd = jnp.pad(state.min_d2.astype(jnp.float32), (0, pad))
     plb = jnp.pad(state.point_lb.astype(jnp.float32), (0, pad))
 
+    dcol = delta.astype(jnp.float32)[:, None]
+
     def blk(args):
         x, xn, vld, a0, m0, l0, th, ab = args
-        return _tile_assign_pruned(x, xn, cents, vld, a0, m0, l0, delta,
+        return _tile_assign_pruned(x, xn, cents, vld, a0, m0, l0, dcol,
                                    th, ab)
 
+    rows = lambda v: v.reshape(-1, 1, tile)  # noqa: E731
+    scal = lambda v: v.reshape(-1, 1, 1)  # noqa: E731
     a, m, part, gap, lb, pruned, tsums, tcounts = jax.lax.map(
-        blk, (pts.reshape(-1, tile, d), nrm.reshape(-1, tile),
-              valid.reshape(-1, tile), pa.reshape(-1, tile),
-              pmd.reshape(-1, tile), plb.reshape(-1, tile), thresh, absorb))
-    return (a.reshape(-1)[:n], m.reshape(-1)[:n], part, gap,
-            lb.reshape(-1)[:n], pruned, tsums, tcounts)
+        blk, (pts.reshape(-1, tile, d), rows(nrm), rows(valid), rows(pa),
+              rows(pmd), rows(plb), scal(thresh), scal(absorb)))
+    n_tiles = part.shape[0]
+    return (a.reshape(-1)[:n], m.reshape(-1)[:n], part.reshape(n_tiles),
+            gap.reshape(n_tiles), lb.reshape(-1)[:n],
+            pruned.reshape(n_tiles), tsums, tcounts[:, 0])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -843,7 +841,7 @@ class MeshBackend(Backend):
         return jax.lax.psum(x, self.axes)
 
     def pvary(self, x):
-        return collectives.pvary(x, self.axes)
+        return jax.lax.pcast(x, self.axes, to="varying")
 
 
 _LOCAL_BACKENDS: dict[str, Callable[..., Backend]] = {
@@ -883,6 +881,20 @@ def make_backend(name: Union[str, Backend], **opts) -> Backend:
             f"unknown backend {name!r}; expected one of "
             f"{sorted(_LOCAL_BACKENDS) + ['mesh']}") from None
     return ctor(**opts)
+
+
+def _mesh_scoped(method):
+    """Run a ClusterEngine entry point with a mesh backend's mesh in context.
+    ``jax.make_mesh`` gives Explicit axes by default, and eager work on
+    arrays sharded over such a mesh (the input guards, indexing inside
+    ``shard_map``) needs the mesh set (``jax.set_mesh``)."""
+    @functools.wraps(method)
+    def scoped(self, *args, **kwargs):
+        if not self.backend.distributed:
+            return method(self, *args, **kwargs)
+        with jax.set_mesh(self.backend.mesh):
+            return method(self, *args, **kwargs)
+    return scoped
 
 
 # ---------------------------------------------------------------------------
@@ -1560,16 +1572,15 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
         n_tiles = -(-n_local // tile)
         if bound_gate:
             init_state = BoundState(
-                collectives.pvary(jnp.zeros((n_tiles,), jnp.float32), axes),
-                collectives.pvary(jnp.full((n_tiles,), jnp.inf, jnp.float32),
-                                  axes))
+                backend.pvary(jnp.zeros((n_tiles,), jnp.float32)),
+                backend.pvary(jnp.full((n_tiles,), jnp.inf, jnp.float32)))
         else:
             init_state = None
         first_fn = lambda k0: collectives.dist_gumbel_choice(  # noqa: E731
             k0, jnp.zeros((n_local,), jnp.float32), axes)
         take_fn = lambda i: collectives.take_global(pts, i, axes)  # noqa: E731
-        init_min_d2 = collectives.pvary(
-            jnp.full((n_local,), jnp.inf, jnp.float32), axes)
+        init_min_d2 = backend.pvary(
+            jnp.full((n_local,), jnp.inf, jnp.float32))
 
         if sampler == "rejection":
             hier = proposal == "hier"
@@ -1667,8 +1678,8 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
                 all_tiles=n_tiles * collectives.axis_size(axes),
                 refresh_block=refresh_block, max_attempts=max_attempts,
                 init_min_d2=init_min_d2, init_state=init_state,
-                init_partials=collectives.pvary(
-                    jnp.zeros((n_tiles,), jnp.float32), axes),
+                init_partials=backend.pvary(
+                    jnp.zeros((n_tiles,), jnp.float32)),
                 tile=tile, guard=guard, fault=fault,
                 allreduce=lambda x: jax.lax.psum(x, axes))
 
@@ -1695,7 +1706,7 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
         )
 
     if sampler == "rejection":
-        mapped = collectives.shard_map(
+        mapped = jax.shard_map(
             local_fn, mesh=backend.mesh,
             in_specs=(P(), P(axes)),
             out_specs=(P(), P(), P(axes), P(), P(), P(), P(), P(),
@@ -1708,7 +1719,7 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
                               recovered=rec if guard else None,
                               tightened=tights, supers=sups)
 
-    mapped = collectives.shard_map(
+    mapped = jax.shard_map(
         local_fn, mesh=backend.mesh,
         in_specs=(P(), P(axes)),
         out_specs=(P(), P(), P(axes), P(), P(), P()))
@@ -1977,7 +1988,7 @@ def _fit_mesh(points, init_centroids, weights, backend: MeshBackend,
     del gated  # the skips/prunes/recovered leaves are replicated when
     #            present, absent otherwise; P() is a valid prefix spec for
     #            the empty (None) subtree too
-    mapped = collectives.shard_map(
+    mapped = jax.shard_map(
         local_fn, mesh=backend.mesh,
         in_specs=in_specs,
         out_specs=(P(), P(axes), P(), P(), P(), P(), P()))
@@ -2227,6 +2238,7 @@ class ClusterEngine:
         self.fallback_events: list = []   # (failed, fallback, reason) hops
         self.last_backend: Backend = self.backend
         self._warned_fallback = False
+        self._last_launch = None   # (jitted fn, abstract args), see _launch
 
     # -- autotune plumbing -------------------------------------------------
     def _tune_for(self, n: int, k: int, d: int, dtype):
@@ -2319,7 +2331,33 @@ class ClusterEngine:
                         f"back to {nxt!r}", RuntimeWarning, stacklevel=3)
                     self._warned_fallback = True
 
+    def _launch(self, jitted, *args):
+        """Call the compiled entry ``jitted(*args)``, remembering it (with
+        shape/dtype/sharding stand-ins for the arrays, so no buffer is kept
+        alive) for `lowered_last_call`."""
+        def abstract(a):
+            if isinstance(a, jax.Array):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=a.sharding)
+            return a
+        self._last_launch = (jitted, tuple(abstract(a) for a in args))
+        return jitted(*args)
+
+    def lowered_last_call(self) -> jax.stages.Lowered:
+        """The lowered program of this engine's last compiled call (seed,
+        fit, kmeans, minibatch step or batched call), as the backend that
+        served it ran it. ``.as_text()`` shows, e.g., whether the Pallas
+        kernels compiled (``tpu_custom_call``) or were interpreted."""
+        if self._last_launch is None:
+            raise ValueError("this engine has made no compiled call yet")
+        jitted, args = self._last_launch
+        if not self.backend.distributed:
+            return jitted.lower(*args)
+        with jax.set_mesh(self.backend.mesh):
+            return jitted.lower(*args)
+
     # -- seeding ----------------------------------------------------------
+    @_mesh_scoped
     def seed(self, key: jax.Array, points: jax.Array, k: int, *,
              weights: Optional[jax.Array] = None,
              sampler: str = "cdf",
@@ -2368,8 +2406,8 @@ class ClusterEngine:
         tuned_be, rec = self._tune_for(n, k, points.shape[1], points.dtype)
         sampler, refresh_block, proposal = self._tune_sampler(
             sampler, refresh_block, rec, proposal)
-        res = self._run(lambda be: _seed_jit(
-            key, points, weights, k, be, sampler, self.precision,
+        res = self._run(lambda be: self._launch(
+            _seed_jit, key, points, weights, k, be, sampler, self.precision,
             self.bounds, int(refresh_block), proposal, int(max_attempts),
             self._guard, _fault),
             backend=tuned_be)
@@ -2416,6 +2454,7 @@ class ClusterEngine:
         return res._replace(assignment=a, reorder=perm)
 
     # -- full-batch Lloyd -------------------------------------------------
+    @_mesh_scoped
     def fit(self, points: jax.Array, init_centroids: jax.Array, *,
             max_iters: int = 50, tol: float = 1e-6,
             weights: Optional[jax.Array] = None,
@@ -2469,14 +2508,16 @@ class ClusterEngine:
                 checkpoint_dir=checkpoint_dir,
                 checkpoint_every=int(checkpoint_every))
         else:
-            res = self._run(lambda be: _fit_jit(
-                points, init_centroids, weights, be, max_iters, float(tol),
-                empty, self.precision, self.bounds, self._guard, _fault),
+            res = self._run(lambda be: self._launch(
+                _fit_jit, points, init_centroids, weights, be, max_iters,
+                float(tol), empty, self.precision, self.bounds, self._guard,
+                _fault),
                 backend=tuned_be)
         if rec is not None:
             res = res._replace(tune=rec)
         return self._order_out(res, perm, inv)
 
+    @_mesh_scoped
     def kmeans(self, key: jax.Array, points: jax.Array, k: int, *,
                init: str = "kmeans++", max_iters: int = 50, tol: float = 1e-6,
                sampler: str = "cdf", empty: str = "keep",
@@ -2503,10 +2544,11 @@ class ClusterEngine:
         if init == "kmeans++" and not self.backend.distributed:
             n = points.shape[0]
             guards.check_shape(k, n)
-            res = self._run(lambda be: _kmeans_jit(
-                key, points, weights, k, be, sampler, max_iters, float(tol),
-                empty, self.precision, self.bounds, int(refresh_block),
-                proposal, int(max_attempts), self._guard), backend=tuned_be)
+            res = self._run(lambda be: self._launch(
+                _kmeans_jit, key, points, weights, k, be, sampler, max_iters,
+                float(tol), empty, self.precision, self.bounds,
+                int(refresh_block), proposal, int(max_attempts),
+                self._guard), backend=tuned_be)
             if rec is not None:
                 res = res._replace(tune=rec)
             return self._order_out(res, perm, inv)
@@ -2592,8 +2634,8 @@ class ClusterEngine:
             if perm is not None:
                 batch = jnp.take(batch, perm, axis=0)
             cents, counts, last_inertia, a = self._run(
-                lambda be: _minibatch_jit(cents, counts, batch, be,
-                                          self.precision))
+                lambda be: self._launch(_minibatch_jit, cents, counts, batch,
+                                        be, self.precision))
             seen += 1
             if tol > 0.0:
                 per_point = float(last_inertia) / max(batch.shape[0], 1)
@@ -2644,9 +2686,9 @@ class ClusterEngine:
         tuned_be, rec = self._tune_for(n, k, points.shape[-1], points.dtype)
         sampler, refresh_block, proposal = self._tune_sampler(
             sampler, refresh_block, rec, proposal)
-        res = self._run(lambda be: _seed_batched_jit(
-            keys, points, k, be, sampler, self.precision, self.bounds,
-            int(refresh_block), proposal, int(max_attempts)),
+        res = self._run(lambda be: self._launch(
+            _seed_batched_jit, keys, points, k, be, sampler, self.precision,
+            self.bounds, int(refresh_block), proposal, int(max_attempts)),
             backend=tuned_be)
         return res if rec is None else res._replace(tune=rec)
 
@@ -2684,9 +2726,10 @@ class ClusterEngine:
         if order == "auto":
             order = rec.order if rec is not None else None
         points, _, perm, inv = self._order_in(points, order, batched=True)
-        res = self._run(lambda be: _fit_batched_jit(
-            points, init_centroids, be, max_iters, float(tol), empty,
-            self.precision, self.bounds), backend=tuned_be)
+        res = self._run(lambda be: self._launch(
+            _fit_batched_jit, points, init_centroids, be, max_iters,
+            float(tol), empty, self.precision, self.bounds),
+            backend=tuned_be)
         if rec is not None:
             res = res._replace(tune=rec)
         return self._order_out(res, perm, inv, batched=True)

@@ -146,18 +146,18 @@ def _kmeans_parallel_mesh(key, points, k, rounds, l, be) -> KmeansppResult:
                                 num_segments=n_cand), axes)
         return cands, cand_idx, w
 
-    mapped = collectives.shard_map(local_fn, mesh=be.mesh,
-                                   in_specs=(P(), P(axes)),
-                                   out_specs=(P(), P(), P()))
+    mapped = jax.shard_map(local_fn, mesh=be.mesh,
+                           in_specs=(P(), P(axes)),
+                           out_specs=(P(), P(), P()))
     cands, cand_idx, w = mapped(kin, points)
     red = engine.seed_points(kr, cands, k, w, be.local, "tiled")
     final_idx = cand_idx[red.indices]
 
-    def d2_fn(pp):
-        return jnp.min(pairwise_d2(pp.astype(jnp.float32), red.centroids),
-                       axis=1)
+    def d2_fn(pp, cc):
+        return jnp.min(pairwise_d2(pp.astype(jnp.float32), cc), axis=1)
 
-    final_min_d2 = collectives.shard_map(
-        d2_fn, mesh=be.mesh, in_specs=(P(axes),), out_specs=P(axes))(points)
+    final_min_d2 = jax.shard_map(
+        d2_fn, mesh=be.mesh, in_specs=(P(axes), P()),
+        out_specs=P(axes))(points, red.centroids)
     return KmeansppResult(red.centroids.astype(points.dtype), final_idx,
                           final_min_d2)

@@ -17,15 +17,13 @@ thrust::reduce for sum(D^2)          ->  per-tile partial sums accumulated
 
 Three bandwidth/FLOP optimizations compose on top of that mapping:
 
-* **norm caching** — ``||x||^2`` is computed ONCE per dataset by the tiny
-  prologue kernel (`seed_prologue_pallas`) and streamed as an extra fp32
-  ``(n,)`` input, dropping d FLOPs/point/round from every round kernel.
+* **norm caching** — ``||x||^2`` is computed ONCE per dataset (the prologue)
+  and streamed as an extra fp32 input, dropping d FLOPs/point/round from
+  every round kernel.
 * **mixed-precision streaming** — the point tiles and centroid block keep
   their input dtype all the way into the MXU (`dot_general` with
   ``preferred_element_type=f32``), so bf16 inputs stream at half the HBM
-  bytes with fp32 accumulation and fp32 cached norms. fp32 inputs take
-  bitwise the same path as before (the products of bf16 values are exact in
-  fp32, so this refactor changes no fp32 results).
+  bytes with fp32 accumulation and fp32 cached norms.
 * **exact tile skipping** — the gated variants take a scalar-prefetched
   compacted active-tile index map (`core.bounds.compact_ids`): grid step i
   streams tile ``ids[i]``; steps past ``n_active`` revisit the last active
@@ -35,7 +33,27 @@ Three bandwidth/FLOP optimizations compose on top of that mapping:
   via ``input_output_aliases``, which is exact (see ``core.bounds``).
 
 The matmul form ``||x||^2 - 2 x.c + ||c||^2`` puts the inner product on the
-MXU (d up to 4096 in our integrations vs d=2 in the paper's figures).
+MXU (d up to 4096 in our integrations vs d=2 in the paper's figures); it
+lives in ``core.distance``, shared with the fused backend, and its dots run
+at ``Precision.HIGHEST``: the TPU's default fp32 matmul rounds its operands
+to bf16, whose error the cancellation in the matmul form would amplify into
+wrong assignments.
+
+Layout (what Mosaic, the TPU kernel compiler, accepts): a block's last two
+dims must be multiples of (8, 128) or span the array, and vectors inside a
+kernel are 2-D. So inside the kernels
+
+* per-point vectors (norms, min_d2, center_d, labels) are lane-dense rows:
+  ``(1, n_pad)`` arrays streamed in ``(1, block_n)`` blocks;
+* a tile's distances are ``(k, block_n)`` — centroids on sublanes, points
+  on lanes — so every per-point reduction over centroids yields a row;
+* per-tile scalars (partials, tile max, gaps, pruned counts, gate inputs)
+  are ``(n_tiles, 1, LANES)`` lane-broadcast blocks, one per tile;
+* scalars the whole launch shares (the valid row count, the compacted tile
+  map) ride the scalar-prefetch channel into SMEM.
+
+The jitted wrappers keep the callers' ``(n,)``/``(n_tiles,)`` shapes and do
+the padding and reshaping outside the kernels.
 
 Raw kernels take ``interpret`` EXPLICITLY: ``kernels.ops`` is the single
 place the on-TPU/off-TPU default is chosen — calling a raw kernel without it
@@ -54,44 +72,88 @@ from jax.experimental.pallas import tpu as pltpu
 # pure-JAX gate model in core.engine evaluates the same function, so model
 # and kernel prune decisions share a single source of truth
 from repro.core.bounds import seed_point_prune as _seed_point_prune
+# the cached-norm matmul-form D^2 the fused backend evaluates too (the
+# fused==pallas bitwise parity hangs off it)
+from repro.core.distance import tile_d2_min
+
+LANES = 128                      # one lane-row per per-tile scalar block
 
 
-def tile_d2(x_raw, c_raw, xn):
-    """(block_n, k) matmul-form D^2 for one point tile — THE shared round
-    math (lloyd_assign imports it too, so the bitwise fused==pallas parity
-    has a single source of truth).
-
-    ``x_raw``/``c_raw`` keep their input dtype into the MXU (bf16 streams at
-    half width; fp32 is bitwise the historical path) with fp32 accumulation;
-    ``xn`` is the cached fp32 ``||x||^2`` block.
-    """
-    cf = c_raw.astype(jnp.float32)
-    cn = jnp.sum(cf * cf, axis=1)                  # (k_new,)
-    dots = jax.lax.dot_general(x_raw, c_raw, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    return jnp.maximum(xn[:, None] - 2.0 * dots + cn[None, :], 0.0)
+# ---------------------------------------------------------------------------
+# layout helpers shared by every kernel module
+# ---------------------------------------------------------------------------
 
 
-def _tile_d2_min(x_raw, c_raw, xn):
-    """min over the centroid block of `tile_d2` (the seeding-round fold)."""
-    return jnp.min(tile_d2(x_raw, c_raw, xn), axis=1)
+def compiler_params():
+    """Mosaic compiler parameters: the scoped-VMEM limit that goes with the
+    tile budget `ops.pick_block_n` sizes for (``kernels.ops`` owns both)."""
+    from repro.kernels.ops import vmem_limit_bytes
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes())
 
 
-def _round_kernel(n_valid_ref, pts_ref, norms_ref, cents_ref, md_ref,
+def pallas_call(kernel, *, out_shape, **kwargs):
+    """``pl.pallas_call`` whose outputs vary over the mesh axes its operands
+    vary over: inside ``shard_map`` (``check_vma``) the output types must
+    say so, and a kernel's outputs are per-shard wherever its inputs are."""
+    def call(*operands):
+        vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+        shapes = [jax.ShapeDtypeStruct(o.shape, o.dtype, vma=vma)
+                  for o in jax.tree.leaves(out_shape)]
+        if isinstance(out_shape, jax.ShapeDtypeStruct):
+            shapes = shapes[0]
+        return pl.pallas_call(kernel, out_shape=shapes, **kwargs)(*operands)
+    return call
+
+
+def as_row(v: jax.Array, pad: int, fill=0.0) -> jax.Array:
+    """(..., n) per-point vector -> (..., 1, n + pad) lane-dense row."""
+    v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)],
+                constant_values=fill)
+    return v[..., None, :]
+
+
+def as_lanes(v: jax.Array) -> jax.Array:
+    """(..., n_tiles) per-tile scalars -> (..., n_tiles, 1, LANES)."""
+    v = v.astype(jnp.float32)
+    return jnp.broadcast_to(v[..., None, None], v.shape + (1, LANES))
+
+
+def from_lanes(a: jax.Array) -> jax.Array:
+    """Inverse of `as_lanes`: (..., n_tiles, 1, LANES) -> (..., n_tiles)."""
+    return a[..., 0, 0]
+
+
+def lane_spec(index_map) -> pl.BlockSpec:
+    """One tile's (1, 1, LANES) per-tile-scalar block."""
+    return pl.BlockSpec((1, 1, LANES), index_map)
+
+
+def lane_scalar(v: jax.Array) -> jax.Array:
+    """A (1, LANES) lane-broadcast block -> its (1, 1) value."""
+    return jnp.max(v, axis=1, keepdims=True)
+
+
+def lane_fill(v: jax.Array, dtype=jnp.float32) -> jax.Array:
+    """A (1, 1) value (or scalar) -> the (1, LANES) block that stores it."""
+    return jnp.broadcast_to(jnp.asarray(v, dtype), (1, LANES))
+
+
+def row_ids(t, block_n: int) -> jax.Array:
+    """(1, block_n) global row ids of tile ``t``."""
+    return t * block_n + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
+
+
+def _round_kernel(nv_ref, pts_ref, norms_ref, cents_ref, md_ref,
                   out_md_ref, partial_ref, *, block_n: int):
     """Grid step i processes point rows [i*block_n, (i+1)*block_n)."""
     i = pl.program_id(0)
-    md = md_ref[...].astype(jnp.float32)           # (block_n,)
-    xn = norms_ref[...].astype(jnp.float32)        # (block_n,) cached
-    new_md = jnp.minimum(md, _tile_d2_min(pts_ref[...], cents_ref[...], xn))
-
+    md = md_ref[...].astype(jnp.float32)           # (1, block_n)
+    new_md = jnp.minimum(md, tile_d2_min(pts_ref[...], cents_ref[...],
+                                         norms_ref[...]))
     # mask padded tail rows (they must not contribute to the reduction)
-    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    valid = row < n_valid_ref[0]
-    new_md = jnp.where(valid, new_md, 0.0)
-
-    out_md_ref[...] = new_md.astype(out_md_ref.dtype)
-    partial_ref[0] = jnp.sum(new_md)               # thrust::reduce analogue
+    new_md = jnp.where(row_ids(i, block_n) < nv_ref[0], new_md, 0.0)
+    out_md_ref[...] = new_md
+    partial_ref[0] = lane_fill(jnp.sum(new_md, axis=1, keepdims=True))
 
 
 @functools.partial(jax.jit,
@@ -111,38 +173,40 @@ def distance_min_update_pallas(points: jax.Array, norms: jax.Array,
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     pts = jnp.pad(points, ((0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), (0, pad))
-    md = jnp.pad(min_d2, (0, pad), constant_values=jnp.inf)
-    n_valid = jnp.array([n], jnp.int32)
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
 
     if resident:
-        cent_spec = pl.BlockSpec((k_new, d), lambda i: (0, 0))
+        cent_spec = pl.BlockSpec((k_new, d), lambda i, nv: (0, 0))
     else:
         # index_map depends on i mod 1 == 0 block but non-constant lambda forces
         # a refetch each grid step (two-pass global-memory behaviour).
-        cent_spec = pl.BlockSpec((k_new, d), lambda i: (0, i * 0))
+        cent_spec = pl.BlockSpec((k_new, d), lambda i, nv: (0, i * 0))
+    row = pl.BlockSpec((1, block_n), lambda i, nv: (0, i))
 
-    out_md, partials = pl.pallas_call(
+    out_md, partials = pallas_call(
         functools.partial(_round_kernel, block_n=block_n),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),            # n_valid (scalar-ish)
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),  # streamed points
-            pl.BlockSpec((block_n,), lambda i: (i,)),      # cached ||x||^2
-            cent_spec,                                      # centroids
-            pl.BlockSpec((block_n,), lambda i: (i,)),      # min_d2 in
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),      # min_d2 out
-            pl.BlockSpec((1,), lambda i: (i,)),            # per-tile partial
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # n_valid
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec((block_n, d), lambda i, nv: (i, 0)),  # points
+                row,                                # cached ||x||^2
+                cent_spec,                          # centroids
+                row,                                # min_d2 in
+            ],
+            out_specs=[
+                row,                                # min_d2 out
+                lane_spec(lambda i, nv: (i, 0, 0)),  # per-tile partial
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(n_valid, pts, nrm, centroids, md)
-    return out_md[:n], partials
+    )(jnp.array([n], jnp.int32), pts, nrm, centroids, md)
+    return out_md[0, :n], from_lanes(partials)
 
 
 # ---------------------------------------------------------------------------
@@ -172,19 +236,21 @@ def _round_kernel_gated(ids_ref, meta_ref, pts_ref, norms_ref, cents_ref,
     def _compute():
         t = ids_ref[i]                             # the REAL tile id
         md = md_ref[...].astype(jnp.float32)
-        xn = norms_ref[...].astype(jnp.float32)
-        row = t * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-        valid = row < meta_ref[0]
-        prune = valid & _seed_point_prune(md, cdist_ref[...], dc_ref[0],
-                                          margin_ref[0])
-        upd = jnp.minimum(md, _tile_d2_min(pts_ref[...], cents_ref[...], xn))
+        valid = row_ids(t, block_n) < meta_ref[0]
+        prune = valid & _seed_point_prune(md, cdist_ref[...],
+                                          lane_scalar(dc_ref[0]),
+                                          lane_scalar(margin_ref[0]))
+        upd = jnp.minimum(md, tile_d2_min(pts_ref[...], cents_ref[...],
+                                          norms_ref[...]))
         new_md = jnp.where(prune, md, upd)
         new_md = jnp.where(valid, new_md, 0.0)
 
-        out_md_ref[...] = new_md.astype(out_md_ref.dtype)
-        partial_ref[0] = jnp.sum(new_md)
-        tmax_ref[0] = jnp.max(new_md)              # bound state for next round
-        pruned_ref[0] = jnp.sum(prune.astype(jnp.float32))
+        out_md_ref[...] = new_md
+        partial_ref[0] = lane_fill(jnp.sum(new_md, axis=1, keepdims=True))
+        # bound state for next round
+        tmax_ref[0] = lane_fill(jnp.max(new_md, axis=1, keepdims=True))
+        pruned_ref[0] = lane_fill(jnp.sum(prune.astype(jnp.float32), axis=1,
+                                          keepdims=True))
 
 
 @functools.partial(jax.jit,
@@ -215,78 +281,85 @@ def distance_min_update_gated_pallas(points: jax.Array, norms: jax.Array,
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     pts = jnp.pad(points, ((0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), (0, pad))
-    md = jnp.pad(min_d2.astype(jnp.float32), (0, pad),
-                 constant_values=jnp.inf)
-    cd = jnp.pad(center_d.astype(jnp.float32), (0, pad))
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
+    cd = as_row(center_d.astype(jnp.float32), pad)
 
     if resident:
         cent_spec = pl.BlockSpec((k_new, d), lambda i, ids, meta: (0, 0))
     else:
         cent_spec = pl.BlockSpec((k_new, d), lambda i, ids, meta: (0, i * 0))
+    row = pl.BlockSpec((1, block_n), lambda i, ids, meta: (0, ids[i]))
+    one = lane_spec(lambda i, ids, meta: (ids[i], 0, 0))
+    # never-read aliased carries: ANY memory space, no per-step DMA
+    carry = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                      # ids, meta
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, ids, meta: (ids[i], 0)),
-            pl.BlockSpec((block_n,), lambda i, ids, meta: (ids[i],)),
+            row,                                    # norms
             cent_spec,
-            pl.BlockSpec((block_n,), lambda i, ids, meta: (ids[i],)),
-            pl.BlockSpec((block_n,), lambda i, ids, meta: (ids[i],)),  # c_d
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),   # dc
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),   # margin
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),   # prev part
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),   # prev tmax
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),   # zeros
+            row,                                    # min_d2
+            row,                                    # center_d
+            one,                                    # dc
+            one,                                    # margin
+            carry, carry, carry,                    # prev part/tmax, zeros
         ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i, ids, meta: (ids[i],)),
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),
-            pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],)),
-        ],
+        out_specs=[row, one, one, one],
     )
-    out_md, partials, tile_max, pruned = pl.pallas_call(
+    out_md, partials, tile_max, pruned = pallas_call(
         functools.partial(_round_kernel_gated, block_n=block_n),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
         ],
         # skipped tiles reuse their prior min_d2 / partials / tile-max and
         # report zero pruned points (the donated zeros buffer)
         input_output_aliases={5: 0, 9: 1, 10: 2, 11: 3},
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(ids, meta, pts, nrm, centroids, md, cd, dc.astype(jnp.float32),
-      margin.astype(jnp.float32), prev_partials.astype(jnp.float32),
-      prev_tile_max.astype(jnp.float32), jnp.zeros((grid,), jnp.float32))
-    return out_md[:n], partials, tile_max, pruned
+    )(ids.astype(jnp.int32), meta.astype(jnp.int32), pts, nrm, centroids,
+      md, cd, as_lanes(dc), as_lanes(margin), as_lanes(prev_partials),
+      as_lanes(prev_tile_max), jnp.zeros((grid, 1, LANES), jnp.float32))
+    return (out_md[0, :n], from_lanes(partials), from_lanes(tile_max),
+            from_lanes(pruned))
 
 
 # ---------------------------------------------------------------------------
 # single-row gather + distance: the rejection sampler's exact-p evaluation
 # ---------------------------------------------------------------------------
 
+_ROW_BLOCK = 8                   # the row is fetched inside its 8-row tile
 
-def _row_min_d2_kernel(meta_ref, row_ref, cents_ref, out_ref):
+
+def _row_min_d2_kernel(meta_ref, rows_ref, cents_ref, out_ref):
     """One grid step: D^2 of the prefetched row to the nearest of the first
     ``meta[1]`` centroid slots (the rejection loop's pending buffer; slots
     past the count are +inf-masked, so an empty pending block yields +inf and
     ``min(q, +inf) == q`` keeps the accept ratio bitwise at 1).
 
     ``meta = [row_idx, count]`` rides the scalar-prefetch channel: the row
-    index steers the (1, d) point block's DMA — the kernel touches O(d) bytes
-    of the dataset, not a tile — which is the whole point of the rejection
-    sampler (per-proposal work independent of n)."""
-    x = row_ref[...].astype(jnp.float32)           # (1, d)
+    index steers the DMA of the aligned 8-row block holding the row — the
+    kernel touches O(d) bytes of the dataset, not a tile — which is the
+    whole point of the rejection sampler (per-proposal work independent of
+    n). The row is picked out of its block by a one-hot select (exact: the
+    other rows contribute zeros)."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_ROW_BLOCK, 1), 0)
+    pick = sub == meta_ref[0] % _ROW_BLOCK
+    x = jnp.sum(jnp.where(pick, rows_ref[...].astype(jnp.float32), 0.0),
+                axis=0, keepdims=True)             # (1, d)
     c = cents_ref[...].astype(jnp.float32)         # (m, d)
     diff = x - c                                   # broadcast over slots
-    d2 = jnp.sum(diff * diff, axis=1)              # (m,)
+    d2 = jnp.sum(diff * diff, axis=1, keepdims=True)     # (m, 1)
     slot = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
-    out_ref[0] = jnp.min(jnp.where(slot < meta_ref[1], d2, jnp.inf))
+    best = jnp.min(jnp.where(slot < meta_ref[1], d2, jnp.inf), axis=0,
+                   keepdims=True)
+    out_ref[...] = lane_fill(best)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -306,18 +379,22 @@ def row_min_d2_pallas(points: jax.Array, idx: jax.Array,
         num_scalar_prefetch=1,                      # meta = [row, count]
         grid=(1,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, meta: (meta[0], 0)),  # the row
+            pl.BlockSpec((_ROW_BLOCK, d),
+                         lambda i, meta: (meta[0] // _ROW_BLOCK, 0)),
             pl.BlockSpec((m, d), lambda i, meta: (0, 0)),        # pending
         ],
-        out_specs=pl.BlockSpec((1,), lambda i, meta: (0,)),
+        out_specs=pl.BlockSpec((1, LANES), lambda i, meta: (0, 0)),
     )
-    out = pl.pallas_call(
+    pad = (-n) % _ROW_BLOCK
+    out = pallas_call(
         _row_min_d2_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.float32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(meta, points, centroids.astype(points.dtype))
-    return out[0]
+    )(meta, jnp.pad(points, ((0, pad), (0, 0))),
+      centroids.astype(points.dtype))
+    return out[0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -336,13 +413,19 @@ def _tile_cap_kernel(meta_ref, cents_ref, radii_ref, pend_ref, out_ref):
     min_d2 — the Raff bound the rejection sampler shrinks its stale envelope
     with between refreshes. Slots >= count are +inf-masked; count == 0
     yields +inf everywhere (a tightening no-op, which is what keeps
-    refresh_block=1 bitwise on the flat path)."""
-    c = cents_ref[...].astype(jnp.float32)         # (n_tiles, d)
+    refresh_block=1 bitwise on the flat path).
+
+    Tiles ride the lanes: the (m, n_tiles) squared distances accumulate one
+    coordinate at a time from the transposed centers."""
+    ct = cents_ref[...].astype(jnp.float32).T      # (d, n_tiles)
     p = pend_ref[...].astype(jnp.float32)          # (m, d)
-    diff = c[:, None, :] - p[None, :, :]
-    d2 = jnp.sum(diff * diff, axis=2)              # (n_tiles, m)
-    slot = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    dc2 = jnp.min(jnp.where(slot < meta_ref[0], d2, jnp.inf), axis=1)
+    d2 = jnp.zeros((p.shape[0], ct.shape[1]), jnp.float32)
+    for j in range(p.shape[1]):
+        diff = ct[j:j + 1, :] - p[:, j:j + 1]      # (m, n_tiles)
+        d2 = d2 + diff * diff
+    slot = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
+    dc2 = jnp.min(jnp.where(slot < meta_ref[0], d2, jnp.inf), axis=0,
+                  keepdims=True)                   # (1, n_tiles)
     cap = (jnp.sqrt(dc2) + radii_ref[...].astype(jnp.float32)) ** 2
     out_ref[...] = jnp.where(meta_ref[0] > 0, cap, jnp.inf)
 
@@ -362,79 +445,83 @@ def tile_cap_pallas(centers: jax.Array, radii: jax.Array,
         grid=(1,),
         in_specs=[
             pl.BlockSpec((t, d), lambda i, meta: (0, 0)),  # tile centers
-            pl.BlockSpec((t,), lambda i, meta: (0,)),      # tile radii
+            pl.BlockSpec((1, t), lambda i, meta: (0, 0)),  # tile radii
             pl.BlockSpec((m, d), lambda i, meta: (0, 0)),  # pending block
         ],
-        out_specs=pl.BlockSpec((t,), lambda i, meta: (0,)),
+        out_specs=pl.BlockSpec((1, t), lambda i, meta: (0, 0)),
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         _tile_cap_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, t), jnp.float32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(meta, centers, radii.astype(jnp.float32), pending)
-    return out
+    )(meta, centers, radii.astype(jnp.float32)[None, :], pending)
+    return out[0]
 
 
 # ---------------------------------------------------------------------------
-# prologue kernel: cached norms + tile centroid-balls, ONE pass over the data
+# prologue kernel: tile centroid-balls, ONE pass over the data
 # ---------------------------------------------------------------------------
 
 
-def _prologue_kernel(n_valid_ref, pts_ref, norms_ref, center_ref, radius_ref,
-                     cdist_ref, *, block_n: int):
+def _prologue_kernel(nv_ref, pts_ref, center_ref, radius_ref, cdist_ref, *,
+                     block_n: int):
     i = pl.program_id(0)
     x = pts_ref[...].astype(jnp.float32)           # (block_n, d)
-    xn = jnp.sum(x * x, axis=1)
-
-    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    valid = row < n_valid_ref[0]
-    norms_ref[...] = jnp.where(valid, xn, 0.0)
+    valid = row_ids(i, block_n) < nv_ref[0]        # (1, block_n)
+    valid_col = (i * block_n + jax.lax.broadcasted_iota(
+        jnp.int32, (block_n, 1), 0)) < nv_ref[0]   # (block_n, 1)
 
     cnt = jnp.sum(valid.astype(jnp.float32))
-    xm = jnp.where(valid[:, None], x, 0.0)
-    ctr = jnp.sum(xm, axis=0) / jnp.maximum(cnt, 1.0)
-    center_ref[0, :] = ctr
-    d2c = jnp.sum((x - ctr[None, :]) ** 2, axis=1)
-    radius_ref[0] = jnp.sqrt(jnp.max(jnp.where(valid, d2c, 0.0)))
+    xm = jnp.where(valid_col, x, 0.0)
+    ctr = jnp.sum(xm, axis=0, keepdims=True) / jnp.maximum(cnt, 1.0)
+    center_ref[0] = ctr                            # (1, d)
+    dt = (x - ctr).T                               # (d, block_n)
+    d2c = jnp.sum(dt * dt, axis=0, keepdims=True)  # (1, block_n)
+    radius_ref[0] = lane_fill(jnp.sqrt(jnp.max(jnp.where(valid, d2c, 0.0),
+                                               axis=1, keepdims=True)))
     # per-point distance to the ball center — the fine-level seeding bound
     cdist_ref[...] = jnp.where(valid, jnp.sqrt(d2c), 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def seed_prologue_pallas(points: jax.Array, *, block_n: int, interpret: bool):
-    """ONE streaming pass computing everything the round kernels cache:
-    (norms (n,) fp32, tile centers (grid, d) fp32, tile radii (grid,) fp32,
-    center_d (n,) fp32 — each point's distance to its tile ball center, the
-    per-point seeding bound)."""
+    """Everything the round kernels cache: (norms (n,) fp32, tile centers
+    (grid, d) fp32, tile radii (grid,) fp32, center_d (n,) fp32 — each
+    point's distance to its tile ball center, the per-point seeding bound).
+
+    The norms come from `core.bounds.point_norms`, the one definition every
+    backend caches (they must agree bitwise across backends); the kernel
+    makes the single streaming pass for the tile balls."""
+    from repro.core.bounds import point_norms
+
     n, d = points.shape
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     pts = jnp.pad(points, ((0, pad), (0, 0)))
-    n_valid = jnp.array([n], jnp.int32)
 
-    norms, centers, radii, center_d = pl.pallas_call(
+    centers, radii, center_d = pallas_call(
         functools.partial(_prologue_kernel, block_n=block_n),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # n_valid
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((block_n, d), lambda i, nv: (i, 0))],
+            out_specs=[
+                pl.BlockSpec((1, 1, d), lambda i, nv: (i, 0, 0)),
+                lane_spec(lambda i, nv: (i, 0, 0)),
+                pl.BlockSpec((1, block_n), lambda i, nv: (0, i)),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid, d), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(n_valid, pts)
-    return norms[:n], centers, radii, center_d[:n]
+    )(jnp.array([n], jnp.int32), pts)
+    return (point_norms(points), centers[:, 0, :], from_lanes(radii),
+            center_d[0, :n])
 
 
 # ---------------------------------------------------------------------------
@@ -442,7 +529,7 @@ def seed_prologue_pallas(points: jax.Array, *, block_n: int, interpret: bool):
 # ---------------------------------------------------------------------------
 
 
-def _round_kernel_batched(n_valid_ref, pts_ref, norms_ref, cents_ref, md_ref,
+def _round_kernel_batched(nv_ref, pts_ref, norms_ref, cents_ref, md_ref,
                           out_md_ref, partial_ref, *, block_n: int):
     """Grid step (b, i) processes rows [i*block_n, (i+1)*block_n) of problem b.
 
@@ -450,16 +537,12 @@ def _round_kernel_batched(n_valid_ref, pts_ref, norms_ref, cents_ref, md_ref,
     block. The centroid block is re-fetched per problem (it differs per b) but
     stays resident across the inner i steps."""
     i = pl.program_id(1)
-    md = md_ref[0].astype(jnp.float32)             # (block_n,)
-    xn = norms_ref[0].astype(jnp.float32)
-    new_md = jnp.minimum(md, _tile_d2_min(pts_ref[0], cents_ref[0], xn))
-
-    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    valid = row < n_valid_ref[0]
-    new_md = jnp.where(valid, new_md, 0.0)
-
-    out_md_ref[0] = new_md.astype(out_md_ref.dtype)
-    partial_ref[0, 0] = jnp.sum(new_md)
+    md = md_ref[0].astype(jnp.float32)             # (1, block_n)
+    new_md = jnp.minimum(md, tile_d2_min(pts_ref[0], cents_ref[0],
+                                         norms_ref[0]))
+    new_md = jnp.where(row_ids(i, block_n) < nv_ref[0], new_md, 0.0)
+    out_md_ref[0] = new_md
+    partial_ref[0, 0] = lane_fill(jnp.sum(new_md, axis=1, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -479,31 +562,33 @@ def distance_min_update_batched_pallas(points: jax.Array, norms: jax.Array,
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pad)))
-    md = jnp.pad(min_d2, ((0, 0), (0, pad)), constant_values=jnp.inf)
-    n_valid = jnp.array([n], jnp.int32)
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
+    row = pl.BlockSpec((1, 1, block_n), lambda b, i, nv: (b, 0, i))
 
-    out_md, partials = pl.pallas_call(
+    out_md, partials = pallas_call(
         functools.partial(_round_kernel_batched, block_n=block_n),
-        grid=(B, grid),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, i: (0,)),
-            pl.BlockSpec((1, block_n, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, k_new, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, i)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # n_valid
+            grid=(B, grid),
+            in_specs=[
+                pl.BlockSpec((1, block_n, d), lambda b, i, nv: (b, i, 0)),
+                row,
+                pl.BlockSpec((1, k_new, d), lambda b, i, nv: (b, 0, 0)),
+                row,
+            ],
+            out_specs=[
+                row,
+                pl.BlockSpec((1, 1, 1, LANES), lambda b, i, nv: (b, i, 0, 0)),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, n + pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(n_valid, pts, nrm, centroids, md)
-    return out_md[:, :n], partials
+    )(jnp.array([n], jnp.int32), pts, nrm, centroids, md)
+    return out_md[:, 0, :n], from_lanes(partials)
 
 
 def _round_kernel_gated_batched(ids_ref, nact_ref, nv_ref, pts_ref, norms_ref,
@@ -521,19 +606,20 @@ def _round_kernel_gated_batched(ids_ref, nact_ref, nv_ref, pts_ref, norms_ref,
     def _compute():
         t = ids_ref[b, i]
         md = md_ref[0].astype(jnp.float32)
-        xn = norms_ref[0].astype(jnp.float32)
-        row = t * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-        valid = row < nv_ref[0]
-        prune = valid & _seed_point_prune(md, cdist_ref[0], dc_ref[0, 0],
-                                          margin_ref[0, 0])
-        upd = jnp.minimum(md, _tile_d2_min(pts_ref[0], cents_ref[0], xn))
+        valid = row_ids(t, block_n) < nv_ref[0]
+        prune = valid & _seed_point_prune(md, cdist_ref[0],
+                                          lane_scalar(dc_ref[0, 0]),
+                                          lane_scalar(margin_ref[0, 0]))
+        upd = jnp.minimum(md, tile_d2_min(pts_ref[0], cents_ref[0],
+                                          norms_ref[0]))
         new_md = jnp.where(prune, md, upd)
         new_md = jnp.where(valid, new_md, 0.0)
 
-        out_md_ref[0] = new_md.astype(out_md_ref.dtype)
-        partial_ref[0, 0] = jnp.sum(new_md)
-        tmax_ref[0, 0] = jnp.max(new_md)
-        pruned_ref[0, 0] = jnp.sum(prune.astype(jnp.float32))
+        out_md_ref[0] = new_md
+        partial_ref[0, 0] = lane_fill(jnp.sum(new_md, axis=1, keepdims=True))
+        tmax_ref[0, 0] = lane_fill(jnp.max(new_md, axis=1, keepdims=True))
+        pruned_ref[0, 0] = lane_fill(jnp.sum(prune.astype(jnp.float32),
+                                             axis=1, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -551,52 +637,46 @@ def distance_min_update_gated_batched_pallas(
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pad)))
-    md = jnp.pad(min_d2.astype(jnp.float32), ((0, 0), (0, pad)),
-                 constant_values=jnp.inf)
-    cd = jnp.pad(center_d.astype(jnp.float32), ((0, 0), (0, pad)))
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
+    cd = as_row(center_d.astype(jnp.float32), pad)
     nv = jnp.array([n], jnp.int32)
 
+    row = pl.BlockSpec((1, 1, block_n),
+                       lambda b, i, ids, na, nv: (b, 0, ids[b, i]))
+    one = pl.BlockSpec((1, 1, 1, LANES),
+                       lambda b, i, ids, na, nv: (b, ids[b, i], 0, 0))
+    carry = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                      # ids, n_active, n_valid
         grid=(B, grid),
         in_specs=[
             pl.BlockSpec((1, block_n, d),
                          lambda b, i, ids, na, nv: (b, ids[b, i], 0)),
-            pl.BlockSpec((1, block_n),
-                         lambda b, i, ids, na, nv: (b, ids[b, i])),
+            row,
             pl.BlockSpec((1, k_new, d), lambda b, i, ids, na, nv: (b, 0, 0)),
-            pl.BlockSpec((1, block_n),
-                         lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, block_n),
-                         lambda b, i, ids, na, nv: (b, ids[b, i])),   # c_d
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
+            row,
+            row,                                    # c_d
+            one, one,                               # dc, margin
+            carry, carry, carry,                    # prev part/tmax, zeros
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_n),
-                         lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
-            pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i])),
-        ],
+        out_specs=[row, one, one, one],
     )
-    out_md, partials, tile_max, pruned = pl.pallas_call(
+    out_md, partials, tile_max, pruned = pallas_call(
         functools.partial(_round_kernel_gated_batched, block_n=block_n),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, n + pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
         ],
         input_output_aliases={6: 0, 10: 1, 11: 2, 12: 3},
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(ids.astype(jnp.int32), n_active.astype(jnp.int32), nv, pts, nrm,
-      centroids, md, cd, dc.astype(jnp.float32), margin.astype(jnp.float32),
-      prev_partials.astype(jnp.float32), prev_tile_max.astype(jnp.float32),
-      jnp.zeros((B, grid), jnp.float32))
-    return out_md[:, :n], partials, tile_max, pruned
+      centroids, md, cd, as_lanes(dc), as_lanes(margin),
+      as_lanes(prev_partials), as_lanes(prev_tile_max),
+      jnp.zeros((B, grid, 1, LANES), jnp.float32))
+    return (out_md[:, 0, :n], from_lanes(partials), from_lanes(tile_max),
+            from_lanes(pruned))

@@ -40,6 +40,11 @@ Two kernel families:
   value-noops, pinned bitwise, and the ``pruned`` output counts them. The
   reduction tree is shared by the gated and ungated tiled kernels, which
   is what makes bounded-vs-unbounded fits bitwise comparable end to end.
+
+The layout is ``kmeans_distance``'s: per-point labels/D²/bounds are
+``(1, block_n)`` rows, a tile's distances ``(k, block_n)``, per-tile
+scalars lane-broadcast blocks, and cluster counts ``(1, k)`` rows (the
+one-hot column sums, taken on the MXU so they land on lanes).
 """
 from __future__ import annotations
 
@@ -50,38 +55,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the one shared definition of the cached-norm matmul-form D^2 — the
-# fused==pallas bitwise-parity claims hang off every kernel using it
-from repro.kernels.kmeans_distance import tile_d2 as _tile_d2
+# the one shared definition of the cached-norm matmul-form D^2 and the
+# nearest-centroid fold — the fused==pallas bitwise-parity claims hang off
+# every kernel using them
+from repro.core.distance import HIGHEST, nearest, tile_d2
+from repro.kernels.kmeans_distance import (LANES, as_lanes, as_row,
+                                           compiler_params, from_lanes,
+                                           lane_fill, lane_scalar, lane_spec,
+                                           pallas_call, row_ids)
 # the ONE definition of the fine-level per-point prune test (the pure-JAX
 # gate model evaluates the same function — single source of truth)
 from repro.core.bounds import assign_point_prune as _assign_point_prune
 
 
-def _assign_kernel(n_valid_ref, pts_ref, norms_ref, cents_ref, assign_ref,
+def _onehot_sums(won, valid, x):
+    """Per-cluster sums (k, d) and counts (1, k) of one tile: the one-hot
+    matmuls on the MXU instead of atomics (exact for the 0/1 counts)."""
+    onehot = jnp.where(valid, won.astype(jnp.float32), 0.0)    # (k, block_n)
+    sums = jax.lax.dot_general(onehot, x, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=HIGHEST)
+    counts = jax.lax.dot_general(jnp.ones((1, onehot.shape[1]), jnp.float32),
+                                 onehot, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32,
+                                 precision=HIGHEST)
+    return sums, counts
+
+
+def _won(a, k):
+    """(k, block_n) winner mask of labels ``a`` (1, block_n)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (k, a.shape[1]), 0) == a
+
+
+def assign_tile(x_raw, xn, c_raw, valid):
+    """Plain per-tile assignment: (labels, masked min_d2, sums (k, d),
+    counts (1, k)) — shared by the accumulated kernels and the fused
+    backend's blocked assignment."""
+    d2 = tile_d2(x_raw, c_raw, xn)
+    a, m = nearest(d2)
+    sums, counts = _onehot_sums(_won(a, d2.shape[0]), valid,
+                                x_raw.astype(jnp.float32))
+    return a, jnp.where(valid, m, 0.0), sums, counts
+
+
+def _assign_kernel(nv_ref, pts_ref, norms_ref, cents_ref, assign_ref,
                    md_ref, sums_ref, counts_ref, *, block_n: int):
     i = pl.program_id(0)
-    x = pts_ref[...].astype(jnp.float32)        # (block_n, d) for accumulation
-    xn = norms_ref[...].astype(jnp.float32)
-    d2 = _tile_d2(pts_ref[...], cents_ref[...], xn)     # (block_n, k)
-
-    a = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    m = jnp.min(d2, axis=1)
-
-    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    valid = row < n_valid_ref[0]
-    m = jnp.where(valid, m, 0.0)
-
+    valid = row_ids(i, block_n) < nv_ref[0]
+    a, m, tile_sums, tile_counts = assign_tile(pts_ref[...], norms_ref[...],
+                                               cents_ref[...], valid)
     assign_ref[...] = a
     md_ref[...] = m
-
-    # one-hot matmul instead of atomics: (k, block_n) @ (block_n, d) on the MXU
-    k = cents_ref.shape[0]
-    onehot = (a[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1))
-    onehot = jnp.where(valid[:, None], onehot.astype(jnp.float32), 0.0)
-    tile_sums = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-    tile_counts = jnp.sum(onehot, axis=0)
 
     @pl.when(i == 0)
     def _init():
@@ -105,33 +129,35 @@ def lloyd_assign_pallas(points: jax.Array, norms: jax.Array,
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     pts = jnp.pad(points, ((0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), (0, pad))
-    n_valid = jnp.array([n], jnp.int32)
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    row = pl.BlockSpec((1, block_n), lambda i, nv: (0, i))
 
-    a, md, sums, counts = pl.pallas_call(
+    a, md, sums, counts = pallas_call(
         functools.partial(_assign_kernel, block_n=block_n),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),      # cached ||x||^2
-            pl.BlockSpec((k, d), lambda i: (0, 0)),        # resident
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((k, d), lambda i: (0, 0)),        # VMEM accumulator
-            pl.BlockSpec((k,), lambda i: (0,)),            # VMEM accumulator
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # n_valid
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec((block_n, d), lambda i, nv: (i, 0)),
+                row,                                # cached ||x||^2
+                pl.BlockSpec((k, d), lambda i, nv: (0, 0)),     # resident
+            ],
+            out_specs=[
+                row,
+                row,
+                pl.BlockSpec((k, d), lambda i, nv: (0, 0)),  # accumulator
+                pl.BlockSpec((1, k), lambda i, nv: (0, 0)),  # accumulator
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((n + pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
             jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
+            jax.ShapeDtypeStruct((1, k), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(n_valid, pts, nrm, centroids)
-    return a[:n], md[:n], sums, counts
+    )(jnp.array([n], jnp.int32), pts, nrm, centroids)
+    return a[0, :n], md[0, :n], sums, counts[0]
 
 
 # ---------------------------------------------------------------------------
@@ -139,35 +165,20 @@ def lloyd_assign_pallas(points: jax.Array, norms: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _assign_kernel_batched(n_valid_ref, pts_ref, norms_ref, cents_ref,
+def _assign_kernel_batched(nv_ref, pts_ref, norms_ref, cents_ref,
                            assign_ref, md_ref, sums_ref, counts_ref, *,
                            block_n: int):
     """Grid step (b, i): same math as `_assign_kernel` for problem b's tile i.
 
-    The (1, k, d)/(1, k) accumulators map to problem b's slot; the grid
+    The (1, k, d)/(1, 1, k) accumulators map to problem b's slot; the grid
     iterates i fastest, so `i == 0` re-initializes them exactly once per
     problem."""
     i = pl.program_id(1)
-    x = pts_ref[0].astype(jnp.float32)          # (block_n, d)
-    xn = norms_ref[0].astype(jnp.float32)
-    d2 = _tile_d2(pts_ref[0], cents_ref[0], xn)
-
-    a = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    m = jnp.min(d2, axis=1)
-
-    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    valid = row < n_valid_ref[0]
-    m = jnp.where(valid, m, 0.0)
-
+    valid = row_ids(i, block_n) < nv_ref[0]
+    a, m, tile_sums, tile_counts = assign_tile(pts_ref[0], norms_ref[0],
+                                               cents_ref[0], valid)
     assign_ref[0] = a
     md_ref[0] = m
-
-    k = cents_ref.shape[1]
-    onehot = (a[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1))
-    onehot = jnp.where(valid[:, None], onehot.astype(jnp.float32), 0.0)
-    tile_sums = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-    tile_counts = jnp.sum(onehot, axis=0)
 
     @pl.when(i == 0)
     def _init():
@@ -195,33 +206,35 @@ def lloyd_assign_batched_pallas(points: jax.Array, norms: jax.Array,
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pad)))
-    n_valid = jnp.array([n], jnp.int32)
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    row = pl.BlockSpec((1, 1, block_n), lambda b, i, nv: (b, 0, i))
 
-    a, md, sums, counts = pl.pallas_call(
+    a, md, sums, counts = pallas_call(
         functools.partial(_assign_kernel_batched, block_n=block_n),
-        grid=(B, grid),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, i: (0,)),
-            pl.BlockSpec((1, block_n, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, k, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, k, d), lambda b, i: (b, 0, 0)),   # accumulator
-            pl.BlockSpec((1, k), lambda b, i: (b, 0)),         # accumulator
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # n_valid
+            grid=(B, grid),
+            in_specs=[
+                pl.BlockSpec((1, block_n, d), lambda b, i, nv: (b, i, 0)),
+                row,
+                pl.BlockSpec((1, k, d), lambda b, i, nv: (b, 0, 0)),
+            ],
+            out_specs=[
+                row,
+                row,
+                pl.BlockSpec((1, k, d), lambda b, i, nv: (b, 0, 0)),
+                pl.BlockSpec((1, 1, k), lambda b, i, nv: (b, 0, 0)),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, n + pad), jnp.int32),
-            jax.ShapeDtypeStruct((B, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.float32),
             jax.ShapeDtypeStruct((B, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(n_valid, pts, nrm, centroids)
-    return a[:, :n], md[:, :n], sums, counts
+    )(jnp.array([n], jnp.int32), pts, nrm, centroids)
+    return a[:, 0, :n], md[:, 0, :n], sums, counts[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -231,27 +244,22 @@ def lloyd_assign_batched_pallas(points: jax.Array, norms: jax.Array,
 
 def _tile_assign(x_raw, xn, c_raw, valid):
     """Shared per-tile assignment math for the tiled/gated kernels:
-    (labels, masked min_d2, tile inertia partial, tile second-best gap,
-    per-point second-best lower bound, tile per-cluster sums, tile
-    per-cluster counts). The gap/lb are in DISTANCE units (the movement
-    bound compares them against centroid movement); a k=1 tile has no
-    runner-up, so its gap/lb are +inf."""
-    d2 = _tile_d2(x_raw, c_raw, xn)                     # (block_n, k)
-    k = d2.shape[1]
-    a = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    m = jnp.min(d2, axis=1)
-    won = a[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-    second = jnp.min(jnp.where(won, jnp.inf, d2), axis=1)
+    (labels, masked min_d2, tile inertia partial (1, 1), tile second-best
+    gap (1, 1), per-point second-best lower bound, tile per-cluster sums
+    (k, d), tile per-cluster counts (1, k)). The gap/lb are in DISTANCE
+    units (the movement bound compares them against centroid movement); a
+    k=1 tile has no runner-up, so its gap/lb are +inf."""
+    d2 = tile_d2(x_raw, c_raw, xn)                      # (k, block_n)
+    a, m = nearest(d2)
+    won = _won(a, d2.shape[0])
+    second = jnp.min(jnp.where(won, jnp.inf, d2), axis=0, keepdims=True)
     gap_pt = jnp.sqrt(second) - jnp.sqrt(m)
-    gap = jnp.min(jnp.where(valid, gap_pt, jnp.inf))
+    gap = jnp.min(jnp.where(valid, gap_pt, jnp.inf), axis=1, keepdims=True)
     m = jnp.where(valid, m, 0.0)
-
-    x = x_raw.astype(jnp.float32)
-    onehot = jnp.where(valid[:, None], won.astype(jnp.float32), 0.0)
-    tile_sums = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-    tile_counts = jnp.sum(onehot, axis=0)
-    return a, m, jnp.sum(m), gap, jnp.sqrt(second), tile_sums, tile_counts
+    tile_sums, tile_counts = _onehot_sums(won, valid,
+                                          x_raw.astype(jnp.float32))
+    return (a, m, jnp.sum(m, axis=1, keepdims=True), gap, jnp.sqrt(second),
+            tile_sums, tile_counts)
 
 
 def _tile_assign_pruned(x_raw, xn, c_raw, valid, prev_a, prev_md, prev_lb,
@@ -262,33 +270,29 @@ def _tile_assign_pruned(x_raw, xn, c_raw, valid, prev_a, prev_md, prev_lb,
     (`core.bounds.assign_point_prune`) short-circuit the k-way distance
     recomputation: label, min_d2 come from the carry (bitwise what a fresh
     compute would produce — the exactness argument in ``core.bounds``), and
-    their lb decays by ``absorb`` instead of being re-derived. Returns
-    (labels, masked min_d2, tile partial, tile gap, per-point lb,
-    pruned-point count, tile sums, tile counts)."""
+    their lb decays by ``absorb`` instead of being re-derived. ``delta`` is
+    the (k, 1) movement column, ``thresh``/``absorb`` the tile's (1, 1)
+    scalars. Returns (labels, masked min_d2, tile partial, tile gap,
+    per-point lb, pruned-point count, tile sums, tile counts)."""
     prune = _assign_point_prune(prev_a, prev_md, prev_lb, delta, thresh,
                                 valid)
-    d2 = _tile_d2(x_raw, c_raw, xn)                     # (block_n, k)
-    k = d2.shape[1]
-    a_f = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    m_f = jnp.min(d2, axis=1)
+    d2 = tile_d2(x_raw, c_raw, xn)                      # (k, block_n)
+    a_f, m_f = nearest(d2)
     a = jnp.where(prune, prev_a, a_f)
     m = jnp.where(prune, prev_md, m_f)
-    won = a[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-    second = jnp.min(jnp.where(won, jnp.inf, d2), axis=1)
+    won = _won(a, d2.shape[0])
+    second = jnp.min(jnp.where(won, jnp.inf, d2), axis=0, keepdims=True)
     # pruned rows carry the decayed bound — their fresh second-best was
     # never (conceptually) computed; fresh rows re-derive it exactly
     lb = jnp.where(prune, prev_lb - absorb, jnp.sqrt(second))
     gap_pt = lb - jnp.sqrt(m)
-    gap = jnp.min(jnp.where(valid, gap_pt, jnp.inf))
+    gap = jnp.min(jnp.where(valid, gap_pt, jnp.inf), axis=1, keepdims=True)
     m = jnp.where(valid, m, 0.0)
-
-    x = x_raw.astype(jnp.float32)
-    onehot = jnp.where(valid[:, None], won.astype(jnp.float32), 0.0)
-    tile_sums = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-    tile_counts = jnp.sum(onehot, axis=0)
-    return (a, m, jnp.sum(m), gap, lb,
-            jnp.sum(prune.astype(jnp.float32)), tile_sums, tile_counts)
+    tile_sums, tile_counts = _onehot_sums(won, valid,
+                                          x_raw.astype(jnp.float32))
+    return (a, m, jnp.sum(m, axis=1, keepdims=True), gap, lb,
+            jnp.sum(prune.astype(jnp.float32), axis=1, keepdims=True),
+            tile_sums, tile_counts)
 
 
 def _super_accum(cond_first, ssums_ref, scounts_ref, tsums, tcounts, idx):
@@ -304,19 +308,17 @@ def _super_accum(cond_first, ssums_ref, scounts_ref, tsums, tcounts, idx):
     scounts_ref[idx] = prev_c + tcounts
 
 
-def _assign_tiled_kernel(n_valid_ref, pts_ref, norms_ref, cents_ref,
+def _assign_tiled_kernel(nv_ref, pts_ref, norms_ref, cents_ref,
                          assign_ref, md_ref, partial_ref, gap_ref, ssums_ref,
                          scounts_ref, *, block_n: int, tps: int):
     i = pl.program_id(0)
-    xn = norms_ref[...].astype(jnp.float32)
-    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    valid = row < n_valid_ref[0]
-    a, m, part, gap, _, tsums, tcounts = _tile_assign(pts_ref[...], xn,
-                                                      cents_ref[...], valid)
+    valid = row_ids(i, block_n) < nv_ref[0]
+    a, m, part, gap, _, tsums, tcounts = _tile_assign(
+        pts_ref[...], norms_ref[...], cents_ref[...], valid)
     assign_ref[...] = a
     md_ref[...] = m
-    partial_ref[0] = part
-    gap_ref[0] = gap
+    partial_ref[0] = lane_fill(part)
+    gap_ref[0] = lane_fill(gap)
     _super_accum(i % tps == 0, ssums_ref, scounts_ref, tsums, tcounts, 0)
 
 
@@ -340,37 +342,41 @@ def lloyd_assign_tiled_pallas(points: jax.Array, norms: jax.Array,
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
     pts = jnp.pad(points, ((0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), (0, pad))
-    n_valid = jnp.array([n], jnp.int32)
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    row = pl.BlockSpec((1, block_n), lambda i, nv: (0, i))
+    one = lane_spec(lambda i, nv: (i, 0, 0))
 
-    a, md, partials, gaps, ssums, scounts = pl.pallas_call(
+    a, md, partials, gaps, ssums, scounts = pallas_call(
         functools.partial(_assign_tiled_kernel, block_n=block_n, tps=tps),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),      # cached ||x||^2
-            pl.BlockSpec((k, d), lambda i: (0, 0)),        # resident
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1, k, d), lambda i: (i // tps, 0, 0)),  # super
-            pl.BlockSpec((1, k), lambda i: (i // tps, 0)),        # super
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # n_valid
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec((block_n, d), lambda i, nv: (i, 0)),
+                row,                                # cached ||x||^2
+                pl.BlockSpec((k, d), lambda i, nv: (0, 0)),     # resident
+            ],
+            out_specs=[
+                row,
+                row,
+                one,
+                one,
+                pl.BlockSpec((1, k, d), lambda i, nv: (i // tps, 0, 0)),
+                pl.BlockSpec((1, 1, k), lambda i, nv: (i // tps, 0, 0)),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((n + pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
             jax.ShapeDtypeStruct((n_super, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((n_super, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_super, 1, k), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(n_valid, pts, nrm, centroids)
-    return a[:n], md[:n], partials, gaps, ssums, scounts
+    )(jnp.array([n], jnp.int32), pts, nrm, centroids)
+    return (a[0, :n], md[0, :n], from_lanes(partials), from_lanes(gaps),
+            ssums, scounts[:, 0])
 
 
 def _assign_gated_kernel(ids_ref, meta_ref, pts_ref, norms_ref, cents_ref,
@@ -395,20 +401,18 @@ def _assign_gated_kernel(ids_ref, meta_ref, pts_ref, norms_ref, cents_ref,
     @pl.when(i < meta_ref[1])
     def _compute():
         t = ids_ref[i]                                 # the REAL tile id
-        xn = norms_ref[...].astype(jnp.float32)
-        row = t * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-        valid = row < meta_ref[0]
+        valid = row_ids(t, block_n) < meta_ref[0]
         a, m, part, gap, lb, pruned, tsums, tcounts = _tile_assign_pruned(
-            pts_ref[...], xn, cents_ref[...], valid, pa_ref[...],
+            pts_ref[...], norms_ref[...], cents_ref[...], valid, pa_ref[...],
             pmd_ref[...].astype(jnp.float32),
             plb_ref[...].astype(jnp.float32), delta_ref[...],
-            thresh_ref[0], absorb_ref[0])
+            lane_scalar(thresh_ref[0]), lane_scalar(absorb_ref[0]))
         assign_ref[...] = a
         md_ref[...] = m
         lb_ref[...] = lb
-        partial_ref[0] = part
-        gap_ref[0] = gap
-        pruned_ref[0] = pruned
+        partial_ref[0] = lane_fill(part)
+        gap_ref[0] = lane_fill(gap)
+        pruned_ref[0] = lane_fill(pruned)
         _super_accum(t % tps == 0, ssums_ref, scounts_ref, tsums, tcounts, 0)
 
 
@@ -443,18 +447,18 @@ def lloyd_assign_gated_pallas(points: jax.Array, norms: jax.Array,
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
     pts = jnp.pad(points, ((0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), (0, pad))
-    pa = jnp.pad(prev_assign.astype(jnp.int32), (0, pad))
-    pmd = jnp.pad(prev_min_d2.astype(jnp.float32), (0, pad))
-    plb = jnp.pad(prev_lb.astype(jnp.float32), (0, pad))
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    pa = as_row(prev_assign.astype(jnp.int32), pad)
+    pmd = as_row(prev_min_d2.astype(jnp.float32), pad)
+    plb = as_row(prev_lb.astype(jnp.float32), pad)
 
     # the five pp/pg/pss/psc/pz operands exist ONLY to donate their buffers
     # via input_output_aliases (the kernel never reads them): ANY memory
     # space keeps them in HBM with no per-step VMEM DMA, so active tiles pay
     # zero traffic for those carries and skipped tiles still inherit them
-    carry_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    blk = pl.BlockSpec((block_n,), lambda i, ids, meta: (ids[i],))
-    one = pl.BlockSpec((1,), lambda i, ids, meta: (ids[i],))
+    carry_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    blk = pl.BlockSpec((1, block_n), lambda i, ids, meta: (0, ids[i]))
+    one = lane_spec(lambda i, ids, meta: (ids[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                          # ids, meta
         grid=(grid,),
@@ -462,7 +466,7 @@ def lloyd_assign_gated_pallas(points: jax.Array, norms: jax.Array,
             pl.BlockSpec((block_n, d), lambda i, ids, meta: (ids[i], 0)),
             blk,                                            # norms
             pl.BlockSpec((k, d), lambda i, ids, meta: (0, 0)),   # resident
-            pl.BlockSpec((k,), lambda i, ids, meta: (0,)),  # delta, resident
+            pl.BlockSpec((k, 1), lambda i, ids, meta: (0, 0)),   # delta
             one,                                            # thresh
             one,                                            # absorb
             blk,                                            # prev assign
@@ -477,51 +481,52 @@ def lloyd_assign_gated_pallas(points: jax.Array, norms: jax.Array,
             one,                                            # gap
             pl.BlockSpec((1, k, d),
                          lambda i, ids, meta: (ids[i] // tps, 0, 0)),
-            pl.BlockSpec((1, k), lambda i, ids, meta: (ids[i] // tps, 0)),
+            pl.BlockSpec((1, 1, k),
+                         lambda i, ids, meta: (ids[i] // tps, 0, 0)),
             one,                                            # pruned
         ],
     )
-    a, md, lb, partials, gaps, ssums, scounts, pruned = pl.pallas_call(
+    a, md, lb, partials, gaps, ssums, scounts, pruned = pallas_call(
         functools.partial(_assign_gated_kernel, block_n=block_n, tps=tps),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n + pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
-            jax.ShapeDtypeStruct((n + pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
             jax.ShapeDtypeStruct((n_super, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((n_super, k), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
+            jax.ShapeDtypeStruct((n_super, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
         ],
         # skipped tiles/supers reuse all of their prior output blocks;
         # skipped tiles report zero pruned points (the donated zeros)
         input_output_aliases={8: 0, 9: 1, 10: 2, 11: 3, 12: 4, 13: 5,
                               14: 6, 15: 7},
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(ids, meta, pts, nrm, centroids, delta.astype(jnp.float32),
-      thresh.astype(jnp.float32), absorb.astype(jnp.float32), pa, pmd, plb,
-      prev_partials.astype(jnp.float32), prev_gaps.astype(jnp.float32),
+    )(ids.astype(jnp.int32), meta.astype(jnp.int32), pts, nrm, centroids,
+      delta.astype(jnp.float32)[:, None], as_lanes(thresh), as_lanes(absorb),
+      pa, pmd, plb, as_lanes(prev_partials), as_lanes(prev_gaps),
       prev_super_sums.astype(jnp.float32),
-      prev_super_counts.astype(jnp.float32),
-      jnp.zeros((grid,), jnp.float32))
-    return a[:n], md[:n], lb[:n], partials, gaps, ssums, scounts, pruned
+      prev_super_counts.astype(jnp.float32)[:, None, :],
+      jnp.zeros((grid, 1, LANES), jnp.float32))
+    return (a[0, :n], md[0, :n], lb[0, :n], from_lanes(partials),
+            from_lanes(gaps), ssums, scounts[:, 0], from_lanes(pruned))
 
 
-def _assign_tiled_kernel_batched(n_valid_ref, pts_ref, norms_ref, cents_ref,
+def _assign_tiled_kernel_batched(nv_ref, pts_ref, norms_ref, cents_ref,
                                  assign_ref, md_ref, partial_ref, gap_ref,
                                  ssums_ref, scounts_ref, *, block_n: int,
                                  tps: int):
     i = pl.program_id(1)
-    xn = norms_ref[0].astype(jnp.float32)
-    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    valid = row < n_valid_ref[0]
-    a, m, part, gap, _, tsums, tcounts = _tile_assign(pts_ref[0], xn,
-                                                      cents_ref[0], valid)
+    valid = row_ids(i, block_n) < nv_ref[0]
+    a, m, part, gap, _, tsums, tcounts = _tile_assign(
+        pts_ref[0], norms_ref[0], cents_ref[0], valid)
     assign_ref[0] = a
     md_ref[0] = m
-    partial_ref[0, 0] = part
-    gap_ref[0, 0] = gap
+    partial_ref[0, 0] = lane_fill(part)
+    gap_ref[0, 0] = lane_fill(gap)
     _super_accum(i % tps == 0, ssums_ref, scounts_ref, tsums, tcounts,
                  (0, 0))
 
@@ -538,38 +543,44 @@ def lloyd_assign_tiled_batched_pallas(points: jax.Array, norms: jax.Array,
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
     pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pad)))
-    n_valid = jnp.array([n], jnp.int32)
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    row = pl.BlockSpec((1, 1, block_n), lambda b, i, nv: (b, 0, i))
+    one = pl.BlockSpec((1, 1, 1, LANES), lambda b, i, nv: (b, i, 0, 0))
 
-    a, md, partials, gaps, ssums, scounts = pl.pallas_call(
+    a, md, partials, gaps, ssums, scounts = pallas_call(
         functools.partial(_assign_tiled_kernel_batched, block_n=block_n,
                           tps=tps),
-        grid=(B, grid),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, i: (0,)),
-            pl.BlockSpec((1, block_n, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, k, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1, k, d), lambda b, i: (b, i // tps, 0, 0)),
-            pl.BlockSpec((1, 1, k), lambda b, i: (b, i // tps, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # n_valid
+            grid=(B, grid),
+            in_specs=[
+                pl.BlockSpec((1, block_n, d), lambda b, i, nv: (b, i, 0)),
+                row,
+                pl.BlockSpec((1, k, d), lambda b, i, nv: (b, 0, 0)),
+            ],
+            out_specs=[
+                row,
+                row,
+                one,
+                one,
+                pl.BlockSpec((1, 1, k, d),
+                             lambda b, i, nv: (b, i // tps, 0, 0)),
+                pl.BlockSpec((1, 1, 1, k),
+                             lambda b, i, nv: (b, i // tps, 0, 0)),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, n + pad), jnp.int32),
-            jax.ShapeDtypeStruct((B, n + pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
             jax.ShapeDtypeStruct((B, n_super, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_super, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_super, 1, k), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(n_valid, pts, nrm, centroids)
-    return a[:, :n], md[:, :n], partials, gaps, ssums, scounts
+    )(jnp.array([n], jnp.int32), pts, nrm, centroids)
+    return (a[:, 0, :n], md[:, 0, :n], from_lanes(partials),
+            from_lanes(gaps), ssums, scounts[:, :, 0])
 
 
 def _assign_gated_kernel_batched(ids_ref, nact_ref, nv_ref, pts_ref,
@@ -588,19 +599,18 @@ def _assign_gated_kernel_batched(ids_ref, nact_ref, nv_ref, pts_ref,
     @pl.when(i < nact_ref[b])
     def _compute():
         t = ids_ref[b, i]
-        xn = norms_ref[0].astype(jnp.float32)
-        row = t * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-        valid = row < nv_ref[0]
+        valid = row_ids(t, block_n) < nv_ref[0]
         a, m, part, gap, lb, pruned, tsums, tcounts = _tile_assign_pruned(
-            pts_ref[0], xn, cents_ref[0], valid, pa_ref[0],
+            pts_ref[0], norms_ref[0], cents_ref[0], valid, pa_ref[0],
             pmd_ref[0].astype(jnp.float32), plb_ref[0].astype(jnp.float32),
-            delta_ref[0], thresh_ref[0, 0], absorb_ref[0, 0])
+            delta_ref[0], lane_scalar(thresh_ref[0, 0]),
+            lane_scalar(absorb_ref[0, 0]))
         assign_ref[0] = a
         md_ref[0] = m
         lb_ref[0] = lb
-        partial_ref[0, 0] = part
-        gap_ref[0, 0] = gap
-        pruned_ref[0, 0] = pruned
+        partial_ref[0, 0] = lane_fill(part)
+        gap_ref[0, 0] = lane_fill(gap)
+        pruned_ref[0, 0] = lane_fill(pruned)
         _super_accum(t % tps == 0, ssums_ref, scounts_ref, tsums, tcounts,
                      (0, 0))
 
@@ -623,17 +633,18 @@ def lloyd_assign_gated_batched_pallas(
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
     pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pad)))
-    pa = jnp.pad(prev_assign.astype(jnp.int32), ((0, 0), (0, pad)))
-    pmd = jnp.pad(prev_min_d2.astype(jnp.float32), ((0, 0), (0, pad)))
-    plb = jnp.pad(prev_lb.astype(jnp.float32), ((0, 0), (0, pad)))
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    pa = as_row(prev_assign.astype(jnp.int32), pad)
+    pmd = as_row(prev_min_d2.astype(jnp.float32), pad)
+    plb = as_row(prev_lb.astype(jnp.float32), pad)
     nv = jnp.array([n], jnp.int32)
 
     # never-read aliased carries: ANY memory space, no per-step DMA
-    carry_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    blk = pl.BlockSpec((1, block_n),
-                       lambda b, i, ids, na, nv: (b, ids[b, i]))
-    one = pl.BlockSpec((1, 1), lambda b, i, ids, na, nv: (b, ids[b, i]))
+    carry_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    blk = pl.BlockSpec((1, 1, block_n),
+                       lambda b, i, ids, na, nv: (b, 0, ids[b, i]))
+    one = pl.BlockSpec((1, 1, 1, LANES),
+                       lambda b, i, ids, na, nv: (b, ids[b, i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                      # ids, n_active, n_valid
         grid=(B, grid),
@@ -642,7 +653,8 @@ def lloyd_assign_gated_batched_pallas(
                          lambda b, i, ids, na, nv: (b, ids[b, i], 0)),
             blk,                                        # norms
             pl.BlockSpec((1, k, d), lambda b, i, ids, na, nv: (b, 0, 0)),
-            pl.BlockSpec((1, k), lambda b, i, ids, na, nv: (b, 0)),  # delta
+            pl.BlockSpec((1, k, 1),
+                         lambda b, i, ids, na, nv: (b, 0, 0)),  # delta
             one,                                        # thresh
             one,                                        # absorb
             blk,                                        # prev assign
@@ -658,34 +670,35 @@ def lloyd_assign_gated_batched_pallas(
             pl.BlockSpec((1, 1, k, d),
                          lambda b, i, ids, na, nv: (b, ids[b, i] // tps,
                                                     0, 0)),
-            pl.BlockSpec((1, 1, k),
-                         lambda b, i, ids, na, nv: (b, ids[b, i] // tps, 0)),
+            pl.BlockSpec((1, 1, 1, k),
+                         lambda b, i, ids, na, nv: (b, ids[b, i] // tps,
+                                                    0, 0)),
             one,                                        # pruned
         ],
     )
-    a, md, lb, partials, gaps, ssums, scounts, pruned = pl.pallas_call(
+    a, md, lb, partials, gaps, ssums, scounts, pruned = pallas_call(
         functools.partial(_assign_gated_kernel_batched, block_n=block_n,
                           tps=tps),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, n + pad), jnp.int32),
-            jax.ShapeDtypeStruct((B, n + pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, n + pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
             jax.ShapeDtypeStruct((B, n_super, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_super, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_super, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid, 1, LANES), jnp.float32),
         ],
         input_output_aliases={9: 0, 10: 1, 11: 2, 12: 3, 13: 4, 14: 5,
                               15: 6, 16: 7},
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(ids.astype(jnp.int32), n_active.astype(jnp.int32), nv, pts, nrm,
-      centroids, delta.astype(jnp.float32), thresh.astype(jnp.float32),
-      absorb.astype(jnp.float32), pa, pmd, plb,
-      prev_partials.astype(jnp.float32), prev_gaps.astype(jnp.float32),
-      prev_super_sums.astype(jnp.float32),
-      prev_super_counts.astype(jnp.float32),
-      jnp.zeros((B, grid), jnp.float32))
-    return (a[:, :n], md[:, :n], lb[:, :n], partials, gaps, ssums, scounts,
-            pruned)
+      centroids, delta.astype(jnp.float32)[..., None], as_lanes(thresh),
+      as_lanes(absorb), pa, pmd, plb, as_lanes(prev_partials),
+      as_lanes(prev_gaps), prev_super_sums.astype(jnp.float32),
+      prev_super_counts.astype(jnp.float32)[:, :, None, :],
+      jnp.zeros((B, grid, 1, LANES), jnp.float32))
+    return (a[:, 0, :n], md[:, 0, :n], lb[:, 0, :n], from_lanes(partials),
+            from_lanes(gaps), ssums, scounts[:, :, 0], from_lanes(pruned))

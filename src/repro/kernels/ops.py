@@ -9,7 +9,11 @@ bypassing these wrappers can never silently run interpreted on real TPU.
 
 Block sizes are chosen so the working set (points tile + resident centroids
 + cached-norms block + accumulators + per-tile partials + bound-state
-blocks) fits a v5e VMEM budget of ~64 MB with double buffering.
+blocks) fits `_VMEM_BUDGET` with double buffering, and every kernel is
+compiled with the scoped-VMEM limit `vmem_limit_bytes` derived from that
+same budget (a v5e core has 128 MiB of VMEM; Mosaic's default scoped limit
+is far below the budget, so without the limit the picked tiles would not
+compile).
 
 The wrappers carry a `custom_vmap` rule: `jax.vmap` over them dispatches to
 the batch-grid kernel variants (one launch with a leading batch grid
@@ -27,7 +31,8 @@ import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
 from repro.kernels.kmeans_distance import (
-    distance_min_update_batched_pallas, distance_min_update_gated_pallas,
+    LANES, distance_min_update_batched_pallas,
+    distance_min_update_gated_pallas,
     distance_min_update_gated_batched_pallas, distance_min_update_pallas,
     row_min_d2_pallas, seed_prologue_pallas, tile_cap_pallas)
 from repro.core.bounds import point_norms  # noqa: F401  (re-exported: the
@@ -41,7 +46,25 @@ from repro.kernels.lloyd_assign import (lloyd_assign_batched_pallas,
                                         lloyd_assign_tiled_batched_pallas,
                                         lloyd_assign_tiled_pallas)
 
-_VMEM_BUDGET = 48 * 1024 * 1024  # leave headroom out of ~64-128MB
+_VMEM_BUDGET = 48 * 1024 * 1024  # what pick_block_n sizes tiles for
+# Mosaic's own scratch (matmul staging, relayouts) comes on top of the
+# buffers the budget counts; the limit leaves that headroom below a v5e
+# core's 128 MiB
+_VMEM_HEADROOM = 2
+# (k, block_n) fp32 values a round kernel keeps live at once: distances,
+# the winner mask / one-hot, and the masked selects built from them
+_D2_LIVE = 4
+_SUBLANES = 8                     # f32 VMEM tiles are (8, LANES)
+
+
+def vmem_limit_bytes() -> int:
+    """The scoped-VMEM limit every kernel compiles with — the one owner of
+    both this limit and the budget `pick_block_n` sizes tiles against."""
+    return _VMEM_HEADROOM * _VMEM_BUDGET
+
+
+def _pad_to(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 # The graceful-degradation order when a Pallas kernel fails to compile or
 # launch: each pallas-flavoured local backend degrades to the fused XLA
@@ -86,25 +109,27 @@ def default_interpret() -> bool:
 def pick_block_n(d: int, k: int, *, dtype_bytes: int = 4,
                  max_block: int = 4096, batched: bool = False) -> int:
     """Largest power-of-two point-tile height whose double-buffered working set
-    fits the VMEM budget. Accounted per grid step:
+    fits the VMEM budget. Accounted per grid step, in VMEM's (8, 128) tiles
+    (a (bn, d) block pads d to 128 lanes; a (1, bn) row pads to 8 sublanes):
 
       2 x (bn, d) point tile           (double-buffered HBM->VMEM stream;
                                         dtype_bytes=2 budgets the half-width
                                         bf16 streaming blocks)
-      2 x (bn,) fp32 cached-norms block (double-buffered alongside the points)
-      (k, d) resident centroid block
-      (bn, k) distance tile + ~4 per-point vectors
-      fp32 accumulators: (k, d) sums + (k,) counts + the per-tile partial
+      2 x (1, bn) fp32 cached-norms row (double-buffered alongside the points)
+      2 x (k, d) centroid block
+      (k, bn) distance tile and the selects built from it + the per-point
+        rows of the round
+      fp32 accumulators: (k, d) sums + (1, k) counts + the per-tile partial
         (the seeding kernel's thrust::reduce analogue)
       bound-state blocks: previous-partial/tile-max in + partial/tile-max out
-        scalars per step, double-buffered (the gated kernel's skip state)
+        lane blocks per step, double-buffered (the gated kernel's skip state)
       per-point bound blocks: the fine-level gates stream the prologue's
-        fp32 ``center_d`` block (seeding, 2 buffers) and the assignment
+        fp32 ``center_d`` row (seeding, 2 buffers) and the assignment
         carries' int32 label + fp32 min_d2 + fp32 point_lb aliased in/out
-        block pairs, plus the (k,) movement vector and the per-tile
-        dc/margin/thresh/absorb scalars
+        row pairs, plus the (k, 1) movement column and the per-tile
+        dc/margin/thresh/absorb lane blocks
       hierarchical accumulator: the tiled/gated Lloyd kernels keep ONE
-        per-SUPER-tile (k, d)+(k,) cluster sums/counts block resident (plus
+        per-SUPER-tile (k, d)+(1, k) cluster sums/counts block resident (plus
         the gated kernel's aliased prev block in flight) — per-tile sums no
         longer stream through VMEM per step
 
@@ -127,21 +152,26 @@ def vmem_working_set(d: int, k: int, bn: int, *, dtype_bytes: int = 4,
     one shared table so tests (and the autotuner's candidate filter) assert
     against the implementation instead of hand-copied constants. Keys name
     the resident buffers; the budget is ``sum(values()) <= _VMEM_BUDGET``."""
+    dp = _pad_to(d, LANES)             # lanes a (., d) block occupies
+    kp = _pad_to(k, _SUBLANES)          # sublanes a (k, .) block occupies
+    kl = _pad_to(k, LANES)             # lanes a (1, k) row occupies
+    row = _SUBLANES * bn                # elements a (1, bn) row occupies
+    lane_blk = _SUBLANES * LANES       # elements a per-tile lane block takes
     ws = {
-        # double-buffered point tile + resident centroids + (bn, k) distance
-        # tile + ~4 per-point vectors, all at the stream dtype
-        "stream": dtype_bytes * (2 * bn * d + k * d + bn * k + 4 * bn),
-        "norms": 4 * 2 * bn,                # cached ||x||^2 (fp32, 2 buffers)
-        "accumulators": 4 * (k * d + k + 8),   # fp32 sums/counts + partial
-        "bound_scalars": 4 * 2 * 4,            # bound-state scalar blocks
-        "super_accumulators": 4 * 2 * (k * d + k),  # super sums/counts out
-                                            #   block (+ gated aliased prev)
-        "point_carries": 4 * 6 * bn,        # assignment/min_d2/point_lb
-                                            #   aliased i/o block pairs
-        "center_d": 4 * 2 * bn,             # center_d block (fp32, 2 bufs)
-        "movement": 4 * k,                  # movement vector (k,)
-        "gate_scalars": 4 * 2 * 8,          # dc/margin/thresh/absorb +
-                                            #   gap/partial/pruned scalars
+        # double-buffered point tile + centroid block + the live (k, bn)
+        # distance values + ~4 per-point rows, all at the stream dtype
+        "stream": dtype_bytes * (2 * bn * dp + 2 * kp * dp
+                                 + _D2_LIVE * kp * bn + 4 * row),
+        "norms": 4 * 2 * row,               # cached ||x||^2 (fp32, 2 buffers)
+        "accumulators": 4 * (kp * dp + _SUBLANES * kl + lane_blk),
+        "bound_scalars": 4 * 2 * 4 * lane_blk,  # bound-state lane blocks
+        "super_accumulators": 4 * 2 * (kp * dp + _SUBLANES * kl),
+        "point_carries": 4 * 6 * row,       # assignment/min_d2/point_lb
+                                            #   aliased i/o row pairs
+        "center_d": 4 * 2 * row,            # center_d row (fp32, 2 bufs)
+        "movement": 4 * kp * LANES,        # movement column (k, 1)
+        "gate_scalars": 4 * 2 * 8 * lane_blk,   # dc/margin/thresh/absorb +
+                                            #   gap/partial/pruned blocks
     }
     if batched:
         ws["batched_centroids"] = dtype_bytes * k * d  # second centroid buf

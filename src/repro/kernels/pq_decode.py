@@ -11,8 +11,9 @@ over the uint8 codes, so HBM traffic per step is
       grid: the paper's constant-memory insight a third time.
 
 Reconstruction inside VMEM uses one-hot matmuls (codes -> one-hot(256) ->
-@ codebook), the TPU-idiomatic replacement for a gather: the MXU does the
-lookup. head_dim 128 / n_sub 16 => 16x less cache traffic.
+@ codebook, one sub-space at a time, codes on the lanes), the TPU-idiomatic
+replacement for a gather: the MXU does the lookup. head_dim 128 / n_sub 16
+=> 16x less cache traffic.
 
 Layout: grid (B, KH, nk); VMEM scratch carries (m, l, acc) for the G query
 heads of one kv head across kv blocks (sequential innermost grid dim).
@@ -29,19 +30,22 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _reconstruct(codes_u8, cb):
-    """codes (block_k, n_sub) uint8 + cb (n_sub, 256, dsub) -> (block_k, d).
-    One-hot matmul per sub-space (MXU lookup, no gather)."""
-    block_k, n_sub = codes_u8.shape
-    n_codes = cb.shape[1]
-    onehot = (codes_u8[:, :, None].astype(jnp.int32)
-              == jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_codes), 2))
-    onehot = onehot.astype(jnp.float32)                  # (bk, n_sub, 256)
-    # (n_sub, bk, 256) @ (n_sub, 256, dsub) -> (n_sub, bk, dsub)
-    parts = jax.lax.dot_general(
-        onehot.transpose(1, 0, 2), cb,
-        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
-    return parts.transpose(1, 0, 2).reshape(block_k, -1)  # (bk, n_sub*dsub)
+def _reconstruct_t(codes, cb_t):
+    """codes (n_sub, block_k) uint8 + cb_t (n_sub, hd, 256) -> (hd, block_k),
+    the block's rows transposed. One one-hot matmul per sub-space (MXU
+    lookup, no gather): ``cb_t[s]`` holds sub-space s's codebook transposed
+    into rows ``s*dsub:(s+1)*dsub`` and zeros elsewhere, so each output
+    element sums one codebook entry with zeros — an exact lookup."""
+    n_sub, block_k = codes.shape
+    code_iota = jax.lax.broadcasted_iota(jnp.int32, (cb_t.shape[2], 1), 0)
+    out = jnp.zeros((cb_t.shape[1], block_k), jnp.float32)
+    for s in range(n_sub):
+        onehot = (code_iota == codes[s:s + 1, :].astype(jnp.int32))
+        out = out + jax.lax.dot_general(
+            cb_t[s], onehot.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
+    return out
 
 
 def _kernel(len_ref, q_ref, kc_ref, vc_ref, kcb_ref, vcb_ref, o_ref,
@@ -62,9 +66,9 @@ def _kernel(len_ref, q_ref, kc_ref, vc_ref, kcb_ref, vcb_ref, o_ref,
     @pl.when(jnp.any(mask))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                 # (G, hd)
-        k = _reconstruct(kc_ref[0, 0], kcb_ref[0])          # (bk, hd)
-        v = _reconstruct(vc_ref[0, 0], vcb_ref[0])
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        k_t = _reconstruct_t(kc_ref[0, 0], kcb_ref[0])      # (hd, bk)
+        v_t = _reconstruct_t(vc_ref[0, 0], vcb_ref[0])
+        s = jax.lax.dot_general(q, k_t, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask, s, _NEG_INF)                    # (G, bk)
         m_prev = m_scr[...]
@@ -74,7 +78,7 @@ def _kernel(len_ref, q_ref, kc_ref, vc_ref, kcb_ref, vcb_ref, o_ref,
         l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1)
         acc_scr[...] = (acc_scr[...] * corr[:, None]
                         + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
+                            p, v_t, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32))
         m_scr[...] = m_new
 
@@ -82,6 +86,17 @@ def _kernel(len_ref, q_ref, kc_ref, vc_ref, kcb_ref, vcb_ref, o_ref,
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+
+
+def _codebook_t(cb: jax.Array) -> jax.Array:
+    """(KH, n_sub, 256, dsub) codebooks -> (KH, n_sub, hd, 256): sub-space
+    s transposed into rows ``s*dsub:(s+1)*dsub``, zeros elsewhere."""
+    kh, n_sub, n_codes, dsub = cb.shape
+    hd = n_sub * dsub
+    col = jnp.arange(hd)
+    rep = cb[..., col % dsub]                            # (KH, n_sub, 256, hd)
+    own = (col // dsub)[None, :] == jnp.arange(n_sub)[:, None]
+    return jnp.where(own[None, :, None, :], rep, 0.0).transpose(0, 1, 3, 2)
 
 
 @functools.partial(jax.jit,
@@ -107,14 +122,16 @@ def pq_decode_attention(q: jax.Array, k_codes: jax.Array, v_codes: jax.Array,
     G = H // KH
     scale = hd ** -0.5
     pad = (-S) % block_k
+    # codes ride the lanes: (B, KH, n_sub, S)
     kc = jnp.pad(k_codes, ((0, 0), (0, pad), (0, 0), (0, 0))) \
-        .transpose(0, 2, 1, 3)                               # (B, KH, S, n_sub)
+        .transpose(0, 2, 3, 1)
     vc = jnp.pad(v_codes, ((0, 0), (0, pad), (0, 0), (0, 0))) \
-        .transpose(0, 2, 1, 3)
+        .transpose(0, 2, 3, 1)
     qh = q.reshape(B, 1, KH, G, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, KH, G, hd)
-    nk = kc.shape[2] // block_k
+    nk = kc.shape[3] // block_k
     len_arr = jnp.asarray([cache_len], jnp.int32)
+    n_codes = k_cb.shape[2]
 
     out = pl.pallas_call(
         functools.partial(_kernel, block_k=block_k, scale=scale),
@@ -122,15 +139,15 @@ def pq_decode_attention(q: jax.Array, k_codes: jax.Array, v_codes: jax.Array,
         in_specs=[
             pl.BlockSpec((1,), lambda b, h, ik: (0,)),
             pl.BlockSpec((1, 1, G, hd), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, n_sub),
-                         lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, n_sub),
-                         lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, n_sub, block_k),
+                         lambda b, h, ik: (b, h, 0, ik)),
+            pl.BlockSpec((1, 1, n_sub, block_k),
+                         lambda b, h, ik: (b, h, 0, ik)),
             # codebooks: VMEM-RESIDENT across the grid (constant-memory
             # analogue — index_map pins the block)
-            pl.BlockSpec((1, n_sub, 256, hd // n_sub),
+            pl.BlockSpec((1, n_sub, hd, n_codes),
                          lambda b, h, ik: (h, 0, 0, 0)),
-            pl.BlockSpec((1, n_sub, 256, hd // n_sub),
+            pl.BlockSpec((1, n_sub, hd, n_codes),
                          lambda b, h, ik: (h, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ik: (b, h, 0, 0)),
@@ -141,7 +158,8 @@ def pq_decode_attention(q: jax.Array, k_codes: jax.Array, v_codes: jax.Array,
             pltpu.VMEM((G, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(len_arr, qh, kc, vc, k_cb, v_cb)
+    )(len_arr, qh, kc, vc, _codebook_t(k_cb.astype(jnp.float32)),
+      _codebook_t(v_cb.astype(jnp.float32)))
     return out.reshape(B, 1, H, hd)      # (B, KH, G, hd): H = kh*G + g
 
 

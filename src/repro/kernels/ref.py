@@ -2,19 +2,23 @@
 dtypes and asserts allclose against these.
 
 The IVF twins (`ivf_scan_ref`, `ivf_adc_scan_ref`) are BITWISE mirrors, not
-merely allclose oracles: they replay the exact op sequence of the Pallas scan
+merely allclose oracles: they replay the op sequence of the Pallas scan
 kernels — ``lax.map`` over queries (NOT vmap, so no batched 3-D contraction
 changes the arithmetic), the same shared gate predicate and lexicographic
-merge, ``jnp.where``-selected carries standing in for ``pl.when``. One
-deliberate deviation: scores are computed for the WHOLE padded array in one
-dot per query and sliced per tile from the materialized result, instead of
-dotting each (block_n, d) tile inside the loop. Per-row dot results are
-invariant to the operand's row count (the fused==pallas precedent), so the
-full-array rows equal the kernel's tile-dot rows bitwise — whereas a dot fed
-by a ``dynamic_slice`` inside the same jit gets the slice fused into it with
-a DIFFERENT accumulation order (observed 1-ulp drift on CPU), which would
-break the mirror. `ivf_bruteforce_topk` is the independent ground truth the
-exactness tests pin both against at ``nprobe == nlist``."""
+merge, ``jnp.where``-selected carries standing in for ``pl.when``. Their
+scores are their own code, not the kernels' scoring functions: the exact
+twin writes the same matmul-form formula in the kernel's (1, N) row layout,
+and the ADC twin looks its LUT and routing terms up by gather where the
+kernel contracts one-hot matrices. One deliberate deviation: scores are
+computed for the WHOLE padded array in one dot per query and sliced per tile
+from the materialized result, instead of dotting each (block_n, d) tile
+inside the loop. Per-row dot results are invariant to the operand's row
+count (the fused==pallas precedent), so the full-array rows equal the
+kernel's tile-dot rows bitwise — whereas a dot fed by a ``dynamic_slice``
+inside the same jit gets the slice fused into it with a DIFFERENT
+accumulation order (observed 1-ulp drift on CPU), which would break the
+mirror. `ivf_bruteforce_topk` is the ground truth the exactness tests pin
+both against at ``nprobe == nlist``."""
 from __future__ import annotations
 
 import functools
@@ -26,13 +30,18 @@ from repro.core.bounds import ivf_gate_skip
 from repro.core.topk import IDX_SENTINEL, init_topk, lex_topk, merge_topk
 
 
+# the oracles' fp32 dots (the TPU's default matmul precision rounds to bf16)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _d2(x: jax.Array, c: jax.Array) -> jax.Array:
     """(n, d) x (k, d) -> (n, k) squared distances in fp32."""
     x = x.astype(jnp.float32)
     c = c.astype(jnp.float32)
     xn = jnp.sum(x * x, axis=-1, keepdims=True)
     cn = jnp.sum(c * c, axis=-1)
-    return jnp.maximum(xn - 2.0 * (x @ c.T) + cn[None, :], 0.0)
+    dots = jnp.matmul(x, c.T, precision=_HIGHEST)
+    return jnp.maximum(xn - 2.0 * dots + cn[None, :], 0.0)
 
 
 def distance_min_update_ref(points: jax.Array, centroids: jax.Array,
@@ -118,7 +127,8 @@ def lloyd_assign_ref(points: jax.Array, centroids: jax.Array):
     m = jnp.min(d2, axis=1)
     k = centroids.shape[0]
     onehot = jax.nn.one_hot(a, k, dtype=jnp.float32)
-    sums = onehot.T @ points.astype(jnp.float32)
+    sums = jnp.matmul(onehot.T, points.astype(jnp.float32),
+                      precision=_HIGHEST)
     counts = jnp.sum(onehot, axis=0)
     return a, m, sums, counts
 
@@ -154,7 +164,7 @@ def lloyd_assign_tiled_ref(points: jax.Array, centroids: jax.Array,
                        0.0).reshape(n_tiles, block_n, k)
     xt = jnp.pad(points.astype(jnp.float32),
                  ((0, pad), (0, 0))).reshape(n_tiles, block_n, d)
-    tile_sums = jnp.einsum("tbk,tbd->tkd", onehot, xt)
+    tile_sums = jnp.einsum("tbk,tbd->tkd", onehot, xt, precision=_HIGHEST)
     tile_counts = jnp.sum(onehot, axis=1)
     spad = (-n_tiles) % tps
     super_sums = jnp.pad(tile_sums, ((0, spad), (0, 0), (0, 0))) \
@@ -168,12 +178,13 @@ def lloyd_assign_tiled_ref(points: jax.Array, centroids: jax.Array,
 def ivf_bruteforce_topk(queries: jax.Array, points: jax.Array,
                         norms: jax.Array, *, k: int):
     """Ground-truth batched top-k: every query against EVERY row, one
-    lexicographic sort. Shares the scan kernels' arithmetic — cached
-    ``||x||^2``, a (n, d) x (1, d) fp32 dot per query (per-row results are
-    invariant to row-block height, the fused==pallas precedent), the same
-    ``max(xn - 2 dots + qn, 0)`` op order, and `core.topk`'s (value, index)
-    tie-break — so the gated scan at ``nprobe == nlist`` must match it
-    BITWISE, which is exactly what the exactness tests assert.
+    lexicographic sort. Written to the scan kernels' formula, not with their
+    code — cached ``||x||^2``, a (1, d) x (n, d) fp32 dot per query (per-row
+    results are invariant to row-block height, the fused==pallas
+    precedent), the same ``max(xn - 2 dots + qn, 0)`` op order, and
+    `core.topk`'s (value, index) tie-break — so the gated scan at
+    ``nprobe == nlist`` must match it BITWISE on one compiler, which is
+    exactly what the exactness tests assert.
 
     Returns (dists (Q, k) fp32, rows (Q, k) int32)."""
     n = points.shape[0]
@@ -183,9 +194,10 @@ def ivf_bruteforce_topk(queries: jax.Array, points: jax.Array,
     def one(q_row):
         q = q_row[None, :].astype(jnp.float32)
         qn = jnp.sum(q * q)
-        dots = jax.lax.dot_general(points, q.astype(points.dtype),
+        dots = jax.lax.dot_general(q.astype(points.dtype), points,
                                    (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)[:, 0]
+                                   preferred_element_type=jnp.float32,
+                                   precision=_HIGHEST)[0]
         d2 = jnp.maximum(nrm - 2.0 * dots + qn, 0.0)
         return lex_topk(d2, rows, k)
 
@@ -217,9 +229,10 @@ def ivf_scan_ref(queries: jax.Array, points: jax.Array, norms: jax.Array,
         # whole-array scores once, sliced per tile below (see module note:
         # bitwise equal to the kernel's per-tile dots, unlike a sliced-
         # operand dot inside the loop)
-        dots = jax.lax.dot_general(
-            pts, q.astype(pts.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]
+        dots = jax.lax.dot_general(q.astype(pts.dtype), pts,
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32,
+                                   precision=_HIGHEST)[0]
         d2_all = jnp.maximum(nrm - 2.0 * dots + qn, 0.0)
 
         def step(i, carry):
@@ -264,8 +277,6 @@ def ivf_adc_scan_ref(queries: jax.Array, lut: jax.Array, qdots: jax.Array,
     dots gathered through one-hot labels — replayed in pure jnp. Same
     signature and returns."""
     n, n_sub = codes.shape
-    n_codes = lut.shape[2]
-    nlist = qdots.shape[1]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     cds = jnp.pad(codes, ((0, pad), (0, 0)))
@@ -275,26 +286,20 @@ def ivf_adc_scan_ref(queries: jax.Array, lut: jax.Array, qdots: jax.Array,
     ctr = centers.astype(jnp.float32)
     rad = radii.astype(jnp.float32)
     iota = jnp.arange(block_n, dtype=jnp.int32)
-    code_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_codes), 2)
-    list_iota = jax.lax.broadcasted_iota(jnp.int32, (1, nlist), 1)
 
     def one(args):
         q_row, q_lut, q_dot, tile_ids, nact = args
         q = q_row[None, :].astype(jnp.float32)
         qn = jnp.sum(q * q)
-        flat_lut = q_lut.astype(jnp.float32).reshape(n_sub * n_codes)
-        qd = q_dot.astype(jnp.float32)
-        # whole-array ADC scores once, sliced per tile below (see module
-        # note on bitwise row-count invariance of the one-hot dots)
-        n_pad = n + pad
-        onehot = (cds[:, :, None].astype(jnp.int32)
-                  == code_iota).astype(jnp.float32)
-        qr = jax.lax.dot_general(
-            onehot.reshape(n_pad, n_sub * n_codes), flat_lut,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        onl = (lab[:, None] == list_iota).astype(jnp.float32)
-        qc = jax.lax.dot_general(onl, qd, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        # whole-array ADC scores once, sliced per tile below: the LUT and
+        # routing-dot terms are table lookups (the kernel's one-hot dots
+        # have one nonzero term each, so they are exact lookups too),
+        # summed over sub-spaces in order
+        lut_f = q_lut.astype(jnp.float32)
+        qr = jnp.zeros((cds.shape[0],), jnp.float32)
+        for s in range(n_sub):
+            qr = qr + lut_f[s][cds[:, s].astype(jnp.int32)]
+        qc = q_dot.astype(jnp.float32)[lab]
         d2_all = jnp.maximum(qn - 2.0 * (qr + qc) + up, 0.0)
 
         def step(i, carry):

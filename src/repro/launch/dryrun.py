@@ -59,10 +59,8 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    from repro.compat import cost_analysis
-
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     mem_d = {}
     for k in ("generated_code_size_in_bytes", "argument_size_in_bytes",
               "output_size_in_bytes", "temp_size_in_bytes",

@@ -19,7 +19,9 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import get_config
 from repro.data.pipeline import DataPipeline
 from repro.data.synthetic import TokenStream
-from repro.launch.step import init_train_state, make_train_step, train_state_shardings
+from repro.launch.mesh import make_test_mesh
+from repro.launch.step import (init_train_state, make_train_step,
+                               train_state_shardings)
 from repro.models.sharding import use_mesh
 from repro.optim import AdamWConfig, CompressConfig
 from repro.train.loop import LoopConfig, train
@@ -51,7 +53,7 @@ def main(argv=None) -> int:
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_test_mesh(d, m)
 
     with use_mesh(mesh):
         state = init_train_state(cfg, jax.random.PRNGKey(args.seed),

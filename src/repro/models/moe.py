@@ -12,7 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.common import ArchConfig
 from repro.models.layers import dense_init, mlp_init, mlp_apply
 
@@ -232,7 +231,7 @@ def _moe_chunk_a2a(p, xf, cfg: ArchConfig):
         aux = _aux_stats(cfg, probs, gate_idx, keep, psum_axes=tok_axes)
         return y, aux
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(tok_axes), P(), P("model"), P("model"), P("model")),
         out_specs=(P(tok_axes), P()))

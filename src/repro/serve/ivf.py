@@ -42,6 +42,7 @@ telemetry counters `core.telemetry.check_ivf_counters` pins.
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import NamedTuple, Optional
 
 import jax
@@ -82,6 +83,8 @@ class SearchResult(NamedTuple):
     probed_lists: jax.Array     # (Q,) int32 non-empty lists routed to
     probed_tiles: jax.Array     # (Q,) int32 tiles the scan visited
     gate_skipped: jax.Array     # (Q,) int32 visited tiles the gate skipped
+    backend: str = ""           # scan backend that served the call (after
+    #                             any kernel-failure fallback)
 
 
 class IvfIndex(NamedTuple):
@@ -256,12 +259,13 @@ class IvfIndex(NamedTuple):
                                axis=1).astype(jnp.int32)
 
         be = self.backend if backend is None else backend
-        dists, rows, skipped = self._scan(q, qdots, ids, n_active, k=int(k),
-                                          mode=mode, gate=gate, backend=be)
+        dists, rows, skipped, served = self._scan(
+            q, qdots, ids, n_active, k=int(k), mode=mode, gate=gate,
+            backend=be)
         return SearchResult(indices=_map_rows(rows, self.perm),
                             dists=dists, probed_lists=probed_lists,
                             probed_tiles=n_active.astype(jnp.int32),
-                            gate_skipped=skipped)
+                            gate_skipped=skipped, backend=served)
 
     def exhaustive(self, queries, k: int) -> tuple[jax.Array, jax.Array]:
         """Brute-force batched top-k over every row — the ground truth
@@ -285,7 +289,9 @@ class IvfIndex(NamedTuple):
     def _scan(self, q, qdots, ids, n_active, *, k: int, mode: str,
               gate: bool, backend: str):
         """Dispatch the gated scan, walking the kernel fallback chain on
-        KernelFailureError (same degradation policy as the engine)."""
+        KernelFailureError (same degradation policy as the engine, which
+        also warns). Returns (dists, rows, gate_skipped, backend that
+        served)."""
         from repro.core.guards import KernelFailureError
         from repro.kernels import ref as kref
 
@@ -321,10 +327,14 @@ class IvfIndex(NamedTuple):
                             ids, n_active, k=kk, block_n=self.block_n,
                             gate=gate)
                 break
-            except KernelFailureError:
-                be = kops.FALLBACK_CHAIN.get(be)
-                if be is None:
+            except KernelFailureError as e:
+                nxt = kops.FALLBACK_CHAIN.get(be)
+                if nxt is None:
                     raise
+                warnings.warn(f"ivf scan backend {be!r} failed ({e}); "
+                              f"falling back to {nxt!r}", RuntimeWarning,
+                              stacklevel=3)
+                be = nxt
         dists, rows, skipped = out
         if k > self.n:      # sentinel-pad the impossible slots
             pad = k - self.n
@@ -332,7 +342,7 @@ class IvfIndex(NamedTuple):
                             constant_values=jnp.inf)
             rows = jnp.pad(rows, ((0, 0), (0, pad)),
                            constant_values=IDX_SENTINEL)
-        return dists, rows, skipped
+        return dists, rows, skipped, be
 
     def _check_offsets(self) -> None:
         """Host-side offset revalidation, ALWAYS on (independent of the
@@ -405,7 +415,9 @@ def _route(q, centroids, centroid_norms, sup_c, sup_r, sup_sizes, *,
     qn = jnp.sum(q * q, axis=1)                                # (Q,)
 
     sc2 = jnp.sum(sup_c * sup_c, axis=1)
-    sd2 = jnp.maximum(qn[:, None] - 2.0 * (q @ sup_c.T) + sc2[None, :], 0.0)
+    hi = jax.lax.Precision.HIGHEST    # exact routing needs fp32 dots
+    sd2 = jnp.maximum(qn[:, None] - 2.0 * jnp.matmul(q, sup_c.T, precision=hi)
+                      + sc2[None, :], 0.0)
     sd = jnp.sqrt(sd2)                                         # (Q, n_sup)
     lb = jnp.maximum(sd - sup_r[None, :], 0.0) ** 2
     ub = (sd + sup_r[None, :]) ** 2
@@ -420,7 +432,7 @@ def _route(q, centroids, centroid_norms, sup_c, sup_r, sup_sizes, *,
                             + jnp.sqrt(qn)[:, None]) ** 2
     survive = lb <= tau_ub[:, None] * (1.0 + bounds._REL) + margin
 
-    qdots = q @ centroids.T                                    # (Q, nlist)
+    qdots = jnp.matmul(q, centroids.T, precision=hi)          # (Q, nlist)
     cd2 = jnp.maximum(qn[:, None] - 2.0 * qdots
                       + centroid_norms[None, :], 0.0)
     sup_of_list = jnp.arange(nlist, dtype=jnp.int32) // jnp.int32(g)
@@ -443,7 +455,8 @@ def _adc_lut(q, cb: kvquant.PQCodebook):
     qsub = q.reshape(q.shape[0], n_sub, dsub)
     return jnp.einsum("qsd,scd->qsc", qsub,
                       cb.centroids.astype(jnp.float32),
-                      preferred_element_type=jnp.float32)
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @jax.jit

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (dist_kmeans, dist_kmeanspp, dist_lloyd, kmeanspp,
                         lloyd, quality, ring_psum, take_global)
 from repro.data.synthetic import blobs
@@ -67,11 +66,12 @@ x = jnp.arange(64, dtype=jnp.float32).reshape(16, 4)
 
 
 def tg(idx):
-    f = shard_map(
+    f = jax.shard_map(
         lambda p: take_global(p, jnp.asarray(idx, jnp.int32),
                               ("data", "model")),
         mesh=mesh, in_specs=P(("data", "model")), out_specs=P())
-    return f(x)
+    with jax.set_mesh(mesh):
+        return f(x)
 
 
 out["take_global_ok"] = all(
@@ -81,10 +81,11 @@ out["take_global_ok"] = all(
 def rp(v):
     # out_specs keeps the data axis: VMA can't statically prove a ppermute
     # ring is replicated, so each shard returns its copy and we check parity
-    f = shard_map(
+    f = jax.shard_map(
         lambda p: ring_psum(jnp.sum(p, keepdims=True), "data"),
         mesh=mesh, in_specs=P(("data",)), out_specs=P(("data",)))
-    return f(v)
+    with jax.set_mesh(mesh):
+        return f(v)
 
 
 v = jnp.arange(8, dtype=jnp.float32)[:, None]
@@ -157,11 +158,12 @@ ktop = jax.random.PRNGKey(21)
 
 
 def topl_dist(l):
-    f = shard_map(
+    f = jax.shard_map(
         lambda w: collectives.dist_gumbel_topl(ktop, w, l,
                                                ("data", "model"))[0],
         mesh=mesh, in_specs=P(("data", "model")), out_specs=P())
-    return f(lw)
+    with jax.set_mesh(mesh):
+        return f(lw)
 
 
 # parity oracle: same per-shard fold_in key schedule, replicated
@@ -213,6 +215,7 @@ with tempfile.TemporaryDirectory() as td:
 # 6. sharded train step == single-device train step (tiny arch)
 # ---------------------------------------------------------------------------
 from repro.configs.registry import get_config
+from repro.launch.mesh import make_test_mesh
 from repro.launch.step import (init_train_state, make_train_step,
                                train_state_shardings)
 from repro.models.sharding import use_mesh
@@ -227,10 +230,11 @@ batch = {"tokens": jax.random.randint(kb, (8, 32), 0, cfg.vocab),
 state0 = init_train_state(cfg, jax.random.PRNGKey(0))
 _, m_single = jax.jit(make_train_step(cfg, opt))(state0, batch)
 
-with use_mesh(mesh):
-    ssh = train_state_shardings(mesh, state0)
+lm_mesh = make_test_mesh()
+with use_mesh(lm_mesh):
+    ssh = train_state_shardings(lm_mesh, state0)
     state_sharded = jax.device_put(state0, ssh)
-    bsh = {k: NamedSharding(mesh, P(("data",), None)) for k in batch}
+    bsh = {k: NamedSharding(lm_mesh, P(("data",), None)) for k in batch}
     jf = jax.jit(make_train_step(cfg, opt), in_shardings=(ssh, bsh),
                  out_shardings=(ssh, None))
     _, m_shard = jf(state_sharded, jax.device_put(batch, bsh))

@@ -10,16 +10,15 @@ import dataclasses
 import json
 import sys
 
-import jax
-
 from repro.configs.common import ShapeConfig
 from repro.configs.registry import get_config
+from repro.launch.mesh import make_test_mesh
 from repro.launch.step import jitted_cell
 from repro.models.sharding import use_mesh
 from repro.roofline.hlo import analyze
 
 out = {}
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_test_mesh()
 TINY_TRAIN = ShapeConfig("t", 128, 8, "train")
 TINY_DECODE = ShapeConfig("d", 256, 8, "decode")
 
@@ -38,8 +37,7 @@ r = analyze(compiled.as_text())
 out["train_flops_positive"] = r["flops"] > 1e6
 out["train_has_allreduce"] = r["collectives"]["by_kind"].get("all-reduce", 0) > 0
 out["mem_analysis_present"] = compiled.memory_analysis() is not None
-from repro.compat import cost_analysis
-out["cost_analysis_present"] = "flops" in cost_analysis(compiled)
+out["cost_analysis_present"] = "flops" in compiled.cost_analysis()
 
 # 2. MoE a2a variant compiles and has all-to-all in the schedule
 cfg_moe = dataclasses.replace(get_config("qwen2-moe-a2.7b", smoke=True),

@@ -332,10 +332,10 @@ def _iter_eqns(jaxpr):
         for v in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
                     v, is_leaf=lambda x: isinstance(
-                        x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                        x, (jax.extend.core.Jaxpr, jax.extend.core.ClosedJaxpr))):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
                     yield from _iter_eqns(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jax.extend.core.Jaxpr):
                     yield from _iter_eqns(sub)
 
 
@@ -582,10 +582,10 @@ def _loop_bodies(jaxpr):
             for v in eqn.params.values():
                 for sub in jax.tree_util.tree_leaves(
                         v, is_leaf=lambda x: isinstance(
-                            x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                            x, (jax.extend.core.Jaxpr, jax.extend.core.ClosedJaxpr))):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
                         yield sub.jaxpr
-                    elif isinstance(sub, jax.core.Jaxpr):
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
                         yield sub
 
 
@@ -700,3 +700,26 @@ def test_kernel_wrappers_handle_ragged_n():
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(float(jnp.sum(partials)), float(want_total),
                                rtol=1e-4)
+
+
+@pytest.mark.parametrize("backend", ["fused", "pallas"])
+def test_lowered_last_call_is_the_program_that_ran(backend):
+    """`lowered_last_call` re-lowers the engine's last compiled call from
+    abstract stand-ins of its arguments: compiled and run on the same
+    inputs, it reproduces that call's result bitwise."""
+    eng = ClusterEngine(backend)
+    with pytest.raises(ValueError, match="no compiled call"):
+        eng.lowered_last_call()
+    pts = _points(n=1024, k=6)
+    key = jax.random.PRNGKey(3)
+    res = eng.kmeans(key, pts, 6, max_iters=5)
+    again = eng.lowered_last_call().compile()(key, pts, None)
+    np.testing.assert_array_equal(np.asarray(again.centroids),
+                                  np.asarray(res.centroids))
+    np.testing.assert_array_equal(np.asarray(again.assignment),
+                                  np.asarray(res.assignment))
+    # the next call replaces it
+    fit = eng.fit(pts, res.centroids, max_iters=3)
+    again = eng.lowered_last_call().compile()(pts, res.centroids, None)
+    np.testing.assert_array_equal(np.asarray(again.centroids),
+                                  np.asarray(fit.centroids))

@@ -69,15 +69,16 @@ def test_seed_poisoned_bound_state_recovers_when_witnessed():
     """A NaN'd carried partial in a tile the gate SKIPS is summed straight
     into the round total (skipped tiles reuse the carry) — the exact blind
     spot a correctness-only reading would miss. Detection fires on a round
-    with skips; recovery is bitwise either way."""
+    whose gate skips the poisoned tile (tile 0: with this key and data that
+    is round 6); recovery is bitwise either way."""
     pts = _coherent()
     eng = ClusterEngine("fused", validate="raise")
     clean = eng.seed(jax.random.PRNGKey(1), pts, 8)
-    assert int(np.asarray(clean.skipped)[6]) > 0    # round 7 skips tiles
+    assert int(np.asarray(clean.skipped)[5]) > 0    # round 6 skips tiles
     hurt = eng.seed(jax.random.PRNGKey(1), pts, 8,
-                    _fault=FaultSpec("nan_state", round=7))
+                    _fault=FaultSpec("nan_state", round=6))
     _same_seed(clean, hurt)
-    assert int(np.asarray(hurt.recovered)[6]) == 1
+    assert int(np.asarray(hurt.recovered)[5]) == 1
     # the same poison in a round that recomputes every tile is overwritten
     # before anything reads it: harmless, not flagged — and still bitwise
     active = eng.seed(jax.random.PRNGKey(1), pts, 8,
