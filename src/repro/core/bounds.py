@@ -116,6 +116,13 @@ class RoundCache(NamedTuple):
     fp32 even when the points stream as bf16. ``centers``/``radii`` are the
     tile centroid-balls the skip bound needs; they are ``None`` when bound
     gating is disabled (norm caching alone does not need them).
+
+    ``rows`` are the points padded to a multiple of the tile height, once
+    per call for every round: the Pallas backend's prologue is the one owner
+    of that pad, and its round and assignment kernels stream these rows as
+    they are (pad rows are masked by the valid count n). They are the points
+    themselves when n is already a tile multiple, and ``None`` on backends
+    that pad nothing (reference, fused).
     """
 
     norms: jax.Array                       # (n,) fp32 ||x||²
@@ -123,6 +130,7 @@ class RoundCache(NamedTuple):
     radii: Optional[jax.Array] = None      # (n_tiles,) fp32 ball radii
     center_d: Optional[jax.Array] = None   # (n,) fp32 d(x, tile center) —
                                            # the per-point seeding bound
+    rows: Optional[jax.Array] = None       # (n_tiles * tile, d) padded points
 
 
 class BoundState(NamedTuple):

@@ -43,9 +43,10 @@ A ``Backend`` provides exactly two round primitives:
 
 plus ``prologue(points, m=, with_bounds=)`` — the once-per-call pass that
 builds the RoundCache (the Pallas backend fuses it into one streaming
-kernel). Mixed precision: the engine streams points/centroids as bf16 when
-``precision='bf16'`` while norms, accumulators, min_d2 and the bound state
-stay fp32.
+kernel, and pads the points to a tile multiple there, once, for every
+round: ``RoundCache.rows``). Mixed precision: the engine streams
+points/centroids as bf16 when ``precision='bf16'`` while norms,
+accumulators, min_d2 and the bound state stay fp32.
 
 plus two trivial hooks (``allreduce``, ``pvary``) that are identity on a
 single device and psum/pcast on a mesh. Every algorithm above is written once
@@ -654,6 +655,21 @@ class FusedBackend(Backend):
         return a, md, sums, counts
 
 
+def _rows(points, cache: Optional[RoundCache]):
+    """What a Pallas round streams: the prologue's once-padded rows (in the
+    stream's dtype, see `_stream_cache`) when the cache has them, else the
+    (n, d) points, which the kernel wrapper pads on each call."""
+    return points if cache is None or cache.rows is None else cache.rows
+
+
+def _stream_cache(cache: Optional[RoundCache], stream) -> Optional[RoundCache]:
+    """Once per phase, outside the loop: the padded rows in the dtype the
+    rounds stream (a bf16 copy under precision='bf16'; pad rows stay 0)."""
+    if cache is None or cache.rows is None or cache.rows.dtype == stream.dtype:
+        return cache
+    return cache._replace(rows=cache.rows.astype(stream.dtype))
+
+
 @dataclasses.dataclass(frozen=True)
 class PallasBackend(Backend):
     """Pallas kernels: VMEM-resident centroids + fused min-update/partials
@@ -664,13 +680,19 @@ class PallasBackend(Backend):
 
     def prologue(self, points, m: int = 1,
                  with_bounds: bool = True) -> RoundCache:
+        """Pads the points to a tile multiple ONCE for the whole call
+        (``RoundCache.rows``): the kernels stream those rows every round,
+        so no round re-pads the (n, d) array. The prologue kernel streams
+        them too."""
         from repro.kernels import ops as kops
         n, d = points.shape
+        tile = self.seed_tile(n, d, m)
+        rows = kops.pad_rows(points, -(-n // tile) * tile)
         if not with_bounds:
-            return RoundCache(kops.point_norms(points))
+            return RoundCache(kops.point_norms(points), rows=rows)
         norms, centers, radii, center_d = kops.seed_prologue(
-            points, block_n=self.seed_tile(n, d, m))
-        return RoundCache(norms, centers, radii, center_d)
+            points, block_n=tile, rows=rows)
+        return RoundCache(norms, centers, radii, center_d, rows)
 
     def seed_round(self, points, c_new, min_d2, weights, *, cache=None,
                    state=None):
@@ -682,6 +704,7 @@ class PallasBackend(Backend):
         # launches share the block choice)
         tile = self.seed_tile(n, d, m)
         norms = None if cache is None else cache.norms
+        rows = _rows(points, cache)
         if (state is not None and weights is None and cache is not None
                 and cache.centers is not None):
             # cache.norms is always populated (and always fp32 — never derive
@@ -691,7 +714,7 @@ class PallasBackend(Backend):
                                                   state.tile_max)
             md, partials, tmax, pruned, skipped = \
                 kops.distance_min_update_gated(
-                    points, c_new, min_d2, norms, cache.center_d, dc, margin,
+                    rows, c_new, min_d2, norms, cache.center_d, dc, margin,
                     state.partials, state.tile_max, active, block_n=tile,
                     resident_centroids=self.resident)
             # per-tile counts are fp32 (kernel vectors); cast BEFORE the
@@ -699,7 +722,7 @@ class PallasBackend(Backend):
             return SeedRound(md, jnp.sum(partials), partials, tmax, skipped,
                              jnp.sum(pruned.astype(jnp.int32)))
         min_d2, partials = kops.distance_min_update(
-            points, c_new, min_d2, norms=norms,
+            rows, c_new, min_d2, norms=norms,
             resident_centroids=self.resident, block_n=tile)
         if weights is not None:
             # weighted partials need the weighted sum; recompute cheaply (the
@@ -733,6 +756,7 @@ class PallasBackend(Backend):
         n, d = points.shape
         tile = self.seed_tile(n, d, centroids.shape[0])
         tps = self.tiles_per_super(-(-n // tile))
+        rows = _rows(points, cache)
         if (state is not None and delta is not None
                 and cache.centers is not None):
             dmax = jnp.max(delta)
@@ -746,7 +770,7 @@ class PallasBackend(Backend):
                                                          state, cache)
             a, md, lb, part, gap, ssums, scounts, pruned_t, skipped = \
                 kops.lloyd_assign_gated(
-                    points, centroids, norms, delta, thresh, absorb,
+                    rows, centroids, norms, delta, thresh, absorb,
                     state.assignment, state.min_d2, state.point_lb,
                     state.partials, state.tile_gap, state.tile_sums,
                     state.tile_counts, active, block_n=tile, tps=tps)
@@ -765,7 +789,7 @@ class PallasBackend(Backend):
                                jnp.sum(scounts, axis=0), new_state, skipped,
                                jnp.sum(pruned_t.astype(jnp.int32)))
         a, md, part, gap, ssums, scounts = kops.lloyd_assign_tiled(
-            points, centroids, norms=norms, block_n=tile, tps=tps)
+            rows, centroids, norms=norms, block_n=tile, tps=tps)
         new_state = BoundState(part, tile_gap=gap, tile_sums=ssums,
                                tile_counts=scounts, assignment=a, min_d2=md)
         return AssignRound(a, md, jnp.sum(ssums, axis=0),
@@ -1373,6 +1397,7 @@ def seed_points(key: jax.Array, points: jax.Array, k: int,
     stream = _stream_of(pts, precision)
     if cache is None:
         cache = backend.prologue(pts, with_bounds=bound_gate)
+    cache = _stream_cache(cache, stream)
     tile = backend.seed_tile(n, d)
     if bound_gate:
         n_tiles = -(-n // tile)
@@ -1567,7 +1592,8 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
         pts = pp.astype(jnp.float32)
         n_local, d = pts.shape
         stream = _stream_of(pts, precision)
-        cache = backend.prologue(pts, with_bounds=bound_gate)
+        cache = _stream_cache(backend.prologue(pts, with_bounds=bound_gate),
+                              stream)
         tile = backend.seed_tile(n_local, d)
         n_tiles = -(-n_local // tile)
         if bound_gate:
@@ -1896,6 +1922,7 @@ def _fit_loop(pts, init_centroids, w, backend: Backend, max_iters, tol,
     if tiled:
         if cache is None:
             cache = backend.prologue(pts, m=k, with_bounds=bound_gate)
+        cache = _stream_cache(cache, stream)
         norms = cache.norms             # once per fit, NOT once per iteration
     else:
         norms = (cache.norms if cache is not None
@@ -2872,6 +2899,7 @@ class ClusterEngine:
         # jitted for the same reason as _seed_checkpointed: the chunked body
         # must consume bitwise the norms/centroid-balls the one-shot fit does
         cache = jax.jit(lambda p: be.prologue(p, m=k, with_bounds=True))(pts)
+        cache = _stream_cache(cache, stream)
         cond, body, make_init = _fit_gated_parts(
             pts, stream, jnp.asarray(init_centroids, jnp.float32), be,
             int(max_iters), float(tol), empty, cache.norms, cache,

@@ -53,7 +53,12 @@ kernel are 2-D. So inside the kernels
   map) ride the scalar-prefetch channel into SMEM.
 
 The jitted wrappers keep the callers' ``(n,)``/``(n_tiles,)`` shapes and do
-the padding and reshaping outside the kernels.
+the padding and reshaping outside the kernels. The round wrappers take the
+valid row count ``n`` from their per-point vectors (``min_d2`` / ``norms``),
+not from the points: the engine's prologue pads the ``(n, d)`` points to a
+tile multiple ONCE per job (``core.bounds.RoundCache.rows``) and every round
+streams those rows as they are (`pad_rows` is then a no-op); callers with
+plain ``(n, d)`` points get the pad on each call.
 
 Raw kernels take ``interpret`` EXPLICITLY: ``kernels.ops`` is the single
 place the on-TPU/off-TPU default is chosen — calling a raw kernel without it
@@ -103,6 +108,19 @@ def pallas_call(kernel, *, out_shape, **kwargs):
             shapes = shapes[0]
         return pl.pallas_call(kernel, out_shape=shapes, **kwargs)(*operands)
     return call
+
+
+def pad_rows(points: jax.Array, n_pad: int) -> jax.Array:
+    """(..., r, d) point rows -> at least ``n_pad`` rows, zero-filled.
+
+    Rows that already reach ``n_pad`` (the prologue's once-padded
+    ``RoundCache.rows``) pass through untouched: rows past ``n_pad`` are
+    whole tiles the grid never visits, and pad rows are masked by the
+    kernels' valid-row count either way."""
+    extra = n_pad - points.shape[-2]
+    if extra <= 0:
+        return points
+    return jnp.pad(points, [(0, 0)] * (points.ndim - 2) + [(0, extra), (0, 0)])
 
 
 def as_row(v: jax.Array, pad: int, fill=0.0) -> jax.Array:
@@ -164,15 +182,17 @@ def distance_min_update_pallas(points: jax.Array, norms: jax.Array,
     """Returns (new_min_d2 (n,), partials (grid,)). sum(partials) == sum(D^2).
 
     ``norms`` is the cached fp32 ``||x||^2`` (n,) from the prologue.
+    ``points`` are the (n, d) points or rows already padded past n (see
+    `pad_rows`); n is ``min_d2``'s length.
     resident=True keeps the centroid block pinned in VMEM across grid steps
     (constant-memory analogue). resident=False re-indexes the centroid block
     every step, modelling the global-memory variant's repeated fetch.
     """
-    n, d = points.shape
+    n, d = min_d2.shape[0], points.shape[1]
     k_new = centroids.shape[0]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = jnp.pad(points, ((0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
 
@@ -274,13 +294,14 @@ def distance_min_update_gated_pallas(points: jax.Array, norms: jax.Array,
     recompute would write. ``center_d``/``dc``/``margin`` are the fine-level
     inputs from the prologue and `core.bounds.seed_gate`; ``pruned`` counts
     per-point short-circuits per tile (zero for skipped tiles via a donated
-    zeros buffer).
+    zeros buffer). ``points`` may be the prologue's once-padded rows, which
+    stream as they are; n is ``min_d2``'s length.
     """
-    n, d = points.shape
+    n, d = min_d2.shape[0], points.shape[1]
     k_new = centroids.shape[0]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = jnp.pad(points, ((0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
     cd = as_row(center_d.astype(jnp.float32), pad)
@@ -486,20 +507,24 @@ def _prologue_kernel(nv_ref, pts_ref, center_ref, radius_ref, cdist_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def seed_prologue_pallas(points: jax.Array, *, block_n: int, interpret: bool):
+def seed_prologue_pallas(points: jax.Array, *, block_n: int, interpret: bool,
+                         rows: jax.Array | None = None):
     """Everything the round kernels cache: (norms (n,) fp32, tile centers
     (grid, d) fp32, tile radii (grid,) fp32, center_d (n,) fp32 — each
     point's distance to its tile ball center, the per-point seeding bound).
 
     The norms come from `core.bounds.point_norms`, the one definition every
     backend caches (they must agree bitwise across backends); the kernel
-    makes the single streaming pass for the tile balls."""
+    makes the single streaming pass for the tile balls. ``rows`` are the
+    same points already padded to a tile multiple (the engine's prologue
+    pads once and hands the rows on to every round); without them the pad
+    happens here."""
     from repro.core.bounds import point_norms
 
     n, d = points.shape
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = jnp.pad(points, ((0, pad), (0, 0)))
+    pts = pad_rows(points if rows is None else rows, n + pad)
 
     centers, radii, center_d = pallas_call(
         functools.partial(_prologue_kernel, block_n=block_n),
@@ -556,12 +581,13 @@ def distance_min_update_batched_pallas(points: jax.Array, norms: jax.Array,
     (new_min_d2 (B, n), partials (B, n_tiles)). Row b of the outputs is
     bitwise what `distance_min_update_pallas` computes for problem b — the
     grid just gains a leading batch dimension, so the many-tenant path pays
-    one kernel launch instead of B."""
-    B, n, d = points.shape
+    one kernel launch instead of B. n is ``min_d2``'s length (the points may
+    be once-padded rows)."""
+    B, n, d = points.shape[0], min_d2.shape[1], points.shape[2]
     k_new = centroids.shape[1]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
     row = pl.BlockSpec((1, 1, block_n), lambda b, i, nv: (b, 0, i))
@@ -631,12 +657,13 @@ def distance_min_update_gated_batched_pallas(
         block_n: int, interpret: bool):
     """Batch-grid bound-gated round: (B, n, d) problems, per-problem compacted
     active-tile maps ids (B, n_tiles) / n_active (B,). Row b is bitwise
-    `distance_min_update_gated_pallas` on problem b."""
-    B, n, d = points.shape
+    `distance_min_update_gated_pallas` on problem b (n is ``min_d2``'s
+    length: the points may be once-padded rows)."""
+    B, n, d = points.shape[0], min_d2.shape[1], points.shape[2]
     k_new = centroids.shape[1]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
     cd = as_row(center_d.astype(jnp.float32), pad)

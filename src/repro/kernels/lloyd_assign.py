@@ -44,7 +44,10 @@ Two kernel families:
 The layout is ``kmeans_distance``'s: per-point labels/D²/bounds are
 ``(1, block_n)`` rows, a tile's distances ``(k, block_n)``, per-tile
 scalars lane-broadcast blocks, and cluster counts ``(1, k)`` rows (the
-one-hot column sums, taken on the MXU so they land on lanes).
+one-hot column sums, taken on the MXU so they land on lanes). As there, the
+wrappers take the valid row count from ``norms``: the points may arrive as
+the prologue's once-padded rows (``core.bounds.RoundCache.rows``), which
+stream as they are; plain ``(n, d)`` points are padded on each call.
 """
 from __future__ import annotations
 
@@ -62,7 +65,7 @@ from repro.core.distance import HIGHEST, nearest, tile_d2
 from repro.kernels.kmeans_distance import (LANES, as_lanes, as_row,
                                            compiler_params, from_lanes,
                                            lane_fill, lane_scalar, lane_spec,
-                                           pallas_call, row_ids)
+                                           pad_rows, pallas_call, row_ids)
 # the ONE definition of the fine-level per-point prune test (the pure-JAX
 # gate model evaluates the same function — single source of truth)
 from repro.core.bounds import assign_point_prune as _assign_point_prune
@@ -124,11 +127,11 @@ def lloyd_assign_pallas(points: jax.Array, norms: jax.Array,
                         interpret: bool):
     """Returns (assignment (n,) int32, min_d2 (n,), sums (k, d), counts (k,)).
     ``norms`` is the cached fp32 ``||x||^2`` (n,)."""
-    n, d = points.shape
+    n, d = norms.shape[0], points.shape[1]
     k = centroids.shape[0]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = jnp.pad(points, ((0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     row = pl.BlockSpec((1, block_n), lambda i, nv: (0, i))
 
@@ -201,11 +204,11 @@ def lloyd_assign_batched_pallas(points: jax.Array, norms: jax.Array,
     int32, min_d2 (B, n), sums (B, k, d), counts (B, k)). Row b matches
     `lloyd_assign_pallas` on problem b; the grid gains a leading batch
     dimension and the per-cluster accumulators gain a per-problem slot."""
-    B, n, d = points.shape
+    B, n, d = points.shape[0], norms.shape[1], points.shape[2]
     k = centroids.shape[1]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     row = pl.BlockSpec((1, 1, block_n), lambda b, i, nv: (b, 0, i))
 
@@ -336,12 +339,12 @@ def lloyd_assign_tiled_pallas(points: jax.Array, norms: jax.Array,
     inertia; ``super_sums.sum(0)`` / ``super_counts.sum(0)`` are the
     centroid-update accumulators — the SAME two-level reduction tree the
     gated kernel produces, so bounded and unbounded fits compare bitwise."""
-    n, d = points.shape
+    n, d = norms.shape[0], points.shape[1]
     k = centroids.shape[0]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
-    pts = jnp.pad(points, ((0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     row = pl.BlockSpec((1, block_n), lambda i, nv: (0, i))
     one = lane_spec(lambda i, nv: (i, 0, 0))
@@ -440,13 +443,15 @@ def lloyd_assign_gated_pallas(points: jax.Array, norms: jax.Array,
     iff the whole super skipped). Inside computed tiles the per-point
     Hamerly bound short-circuits stable points (``delta``/``thresh``/
     ``absorb`` from `core.bounds.assign_point_scalars`). Same returns as
-    `lloyd_assign_tiled_pallas` plus (lb (n,), pruned (n_tiles,))."""
-    n, d = points.shape
+    `lloyd_assign_tiled_pallas` plus (lb (n,), pruned (n_tiles,)).
+    ``points`` may be the prologue's once-padded rows, which stream as they
+    are (the engine's prologue owns that pad); n is ``norms``' length."""
+    n, d = norms.shape[0], points.shape[1]
     k = centroids.shape[0]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
-    pts = jnp.pad(points, ((0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     pa = as_row(prev_assign.astype(jnp.int32), pad)
     pmd = as_row(prev_min_d2.astype(jnp.float32), pad)
@@ -537,12 +542,12 @@ def lloyd_assign_tiled_batched_pallas(points: jax.Array, norms: jax.Array,
                                       tps: int, interpret: bool):
     """Batch-grid tiled assignment over B independent problems in ONE launch;
     row b is bitwise `lloyd_assign_tiled_pallas` on problem b."""
-    B, n, d = points.shape
+    B, n, d = points.shape[0], norms.shape[1], points.shape[2]
     k = centroids.shape[1]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
-    pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     row = pl.BlockSpec((1, 1, block_n), lambda b, i, nv: (b, 0, i))
     one = pl.BlockSpec((1, 1, 1, LANES), lambda b, i, nv: (b, i, 0, 0))
@@ -627,12 +632,12 @@ def lloyd_assign_gated_batched_pallas(
     """Batch-grid bound-gated assignment: per-problem compacted
     (super-aligned) active-tile maps ids (B, n_tiles) / n_active (B,).
     Row b is bitwise `lloyd_assign_gated_pallas` on problem b."""
-    B, n, d = points.shape
+    B, n, d = points.shape[0], norms.shape[1], points.shape[2]
     k = centroids.shape[1]
     pad = (-n) % block_n
     grid = (n + pad) // block_n
     n_super = -(-grid // tps)
-    pts = jnp.pad(points, ((0, 0), (0, pad), (0, 0)))
+    pts = pad_rows(points, n + pad)
     nrm = as_row(norms.astype(jnp.float32), pad)
     pa = as_row(prev_assign.astype(jnp.int32), pad)
     pmd = as_row(prev_min_d2.astype(jnp.float32), pad)

@@ -21,6 +21,12 @@ dimension) instead of relying on the generic pallas batching rule — this is
 what lets the engine's `seed_batched`/`fit_batched` vmap hit real batched
 kernels with the VMEM budget accounted for. The bound-gated wrapper does the
 same for the gated batch-grid kernel (per-problem compacted tile maps).
+
+Row padding: the round wrappers take the valid row count from their
+per-point vectors, so ``points`` may be the engine prologue's once-padded
+rows (``core.bounds.RoundCache.rows``, built by `pad_rows`; the cached
+``norms`` then come with them), which stream as they are. Plain ``(n, d)``
+points are padded inside the kernel wrapper on each call.
 """
 from __future__ import annotations
 
@@ -35,6 +41,8 @@ from repro.kernels.kmeans_distance import (
     distance_min_update_gated_pallas,
     distance_min_update_gated_batched_pallas, distance_min_update_pallas,
     row_min_d2_pallas, seed_prologue_pallas, tile_cap_pallas)
+from repro.kernels.kmeans_distance import pad_rows  # noqa: F401  (re-exported:
+#   the engine's prologue pads the points once per job with it)
 from repro.core.bounds import point_norms  # noqa: F401  (re-exported: the
 #   cached-norm input the kernels stream; wrappers compute it on the fly
 #   when the caller has no prologue cache)
@@ -206,16 +214,19 @@ def _align(points: jax.Array, centroids: jax.Array, norms):
 
 
 def seed_prologue(points: jax.Array, *, block_n: int | None = None,
-                  interpret: bool | None = None):
+                  interpret: bool | None = None, rows: jax.Array | None = None):
     """One streaming pass over the dataset: (norms, tile centers, tile radii)
-    at the seed-tile height — everything the gated round kernels cache."""
+    at the seed-tile height — everything the gated round kernels cache.
+    ``rows`` are the points already padded by `pad_rows` (the engine pads
+    once per job and hands the same rows to every round)."""
     _check_forced()
     n, d = points.shape
     if block_n is None:
         block_n = choose_block_n(n, d, 1, batched=True)
     if interpret is None:
         interpret = default_interpret()
-    return seed_prologue_pallas(points, block_n=block_n, interpret=interpret)
+    return seed_prologue_pallas(points, block_n=block_n, interpret=interpret,
+                                rows=rows)
 
 
 def distance_min_update(points: jax.Array, centroids: jax.Array,
@@ -227,10 +238,12 @@ def distance_min_update(points: jax.Array, centroids: jax.Array,
 
     Returns (new_min_d2 (n,), partials (n_tiles,)) with the tile height
     `choose_block_n(n, d, k)` — the same tile the two-level `tiled` sampler
-    draws from. Under `jax.vmap` this dispatches to the batch-grid kernel
-    (`distance_min_update_batched`), not a per-problem loop."""
+    draws from. n is ``min_d2``'s length (``points`` may be once-padded
+    rows when ``norms`` is given). Under `jax.vmap` this dispatches to the
+    batch-grid kernel (`distance_min_update_batched`), not a per-problem
+    loop."""
     _check_forced()
-    n, d = points.shape
+    n, d = min_d2.shape[0], points.shape[1]
     k = centroids.shape[0]
     user_block = block_n
     if block_n is None:
@@ -269,7 +282,7 @@ def distance_min_update_batched(points: jax.Array, centroids: jax.Array,
     """Batched seeding round: (B, n, d) x (B, k, d) -> ((B, n), (B, n_tiles))
     in one batch-grid kernel launch."""
     _check_forced()
-    _, n, d = points.shape
+    n, d = min_d2.shape[1], points.shape[2]
     k = centroids.shape[1]
     if block_n is None:
         block_n = choose_block_n(n, d, k, batched=True)
@@ -299,12 +312,14 @@ def distance_min_update_gated(points: jax.Array, centroids: jax.Array,
     per-point bound short-circuits rows whose ``min_d2`` provably cannot
     improve. Returns (new_min_d2, partials, tile_max, pruned (n_tiles,),
     skipped). ``block_n`` is required: it must match the tile height of the
-    carried bound state. Under `jax.vmap` this dispatches to the gated
-    batch-grid kernel with per-problem compaction."""
+    carried bound state. ``points`` may be the prologue's once-padded rows
+    (n is ``min_d2``'s length); the grid is ``ceil(n / block_n)`` either
+    way. Under `jax.vmap` this dispatches to the gated batch-grid kernel
+    with per-problem compaction."""
     from repro.core import bounds as bnd
 
     _check_forced()
-    n, d = points.shape
+    n = min_d2.shape[0]
     if interpret is None:
         interpret = default_interpret()
     centroids = centroids.astype(points.dtype)
@@ -462,12 +477,14 @@ def lloyd_assign_tiled(points: jax.Array, centroids: jax.Array, *,
     reduction tree so bounded and unbounded fits compare bitwise. ``tps``
     overrides the super-tile fan-in heuristic (the autotuner's knob); the
     gated twin must be called with the SAME value so the carried super
-    accumulator shapes agree. Under `jax.vmap` this dispatches to the
-    batch-grid kernel."""
+    accumulator shapes agree. With ``norms`` given, ``points`` may be the
+    prologue's once-padded rows (n is ``norms``' length). Under `jax.vmap`
+    this dispatches to the batch-grid kernel."""
     from repro.core import bounds as bnd
 
     _check_forced()
-    n, d = points.shape
+    n = points.shape[0] if norms is None else norms.shape[0]
+    d = points.shape[1]
     k = centroids.shape[0]
     if block_n is None:
         block_n = choose_block_n(n, d, k)
@@ -515,13 +532,14 @@ def lloyd_assign_gated(points: jax.Array, centroids: jax.Array,
     `core.bounds.assign_point_scalars`) drive the per-point Hamerly prune
     inside computed tiles. Returns the `lloyd_assign_tiled` tuple plus
     (lb (n,), pruned (n_tiles,), skipped ()). ``block_n`` is required: it
-    must match the tile height of the carried bound state. Under `jax.vmap`
-    this dispatches to the gated batch-grid kernel with per-problem
-    expansion + compaction."""
+    must match the tile height of the carried bound state. ``points`` may
+    be the prologue's once-padded rows (n is ``norms``' length). Under
+    `jax.vmap` this dispatches to the gated batch-grid kernel with
+    per-problem expansion + compaction."""
     from repro.core import bounds as bnd
 
     _check_forced()
-    n, d = points.shape
+    n = norms.shape[0]
     if interpret is None:
         interpret = default_interpret()
     centroids = centroids.astype(points.dtype)

@@ -152,18 +152,21 @@ def test_bound_state_restore_errors_are_typed(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def _seed_problem():
+def _seed_problem(n=4096):
     from repro.data.synthetic import blobs
-    pts = jnp.asarray(blobs(4096, 2, 6, seed=3, spread=0.05)[0])
+    pts = jnp.asarray(blobs(n, 2, 6, seed=3, spread=0.05)[0])
     return pts, jax.random.PRNGKey(4)
 
 
-def test_checkpointed_seed_matches_plain_and_resumes(tmp_path):
+# the pallas case's n is not a tile multiple: the chunked driver's jitted
+# prologue pads the points once, like the one-shot seed's
+@pytest.mark.parametrize("backend,n", [("fused", 4096), ("pallas", 4096 + 77)])
+def test_checkpointed_seed_matches_plain_and_resumes(tmp_path, backend, n):
     import shutil
     from repro.core.engine import ClusterEngine
     from repro.checkpoint.manager import CheckpointManager as M
-    pts, key = _seed_problem()
-    eng = ClusterEngine("fused")
+    pts, key = _seed_problem(n)
+    eng = ClusterEngine(backend)
     plain = eng.seed(key, pts, 6)
     ck = eng.seed(key, pts, 6, checkpoint_dir=tmp_path, checkpoint_every=2)
     np.testing.assert_array_equal(np.asarray(plain.centroids),

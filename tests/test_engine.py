@@ -42,9 +42,12 @@ def test_make_backend_names():
 # acceptance: same key => bitwise-identical seeds across backends
 # ---------------------------------------------------------------------------
 
+# n=700 is not a tile multiple: the pallas rounds stream the prologue's
+# once-padded rows, whose pad rows must stay masked
+@pytest.mark.parametrize("n", [512, 700])
 @pytest.mark.parametrize("backend", ["reference", "fused", "pallas"])
-def test_seed_parity_across_backends(backend):
-    pts = _points(n=512, k=8)
+def test_seed_parity_across_backends(backend, n):
+    pts = _points(n=n, k=8)
     key = jax.random.PRNGKey(42)
     ref = ClusterEngine("reference", mode="serial").seed(key, pts, 10)
     got = ClusterEngine(backend).seed(key, pts, 10)
@@ -642,19 +645,79 @@ def test_kmeans_computes_point_norms_exactly_once():
             "no kmeans loop may recompute ||x||^2"
 
 
-def test_kmeans_shared_prologue_matches_two_phase_quality():
-    """The fused one-prologue kmeans must cluster exactly as well as the
+def _point_pads(jaxpr, n, d):
+    """pad eqns whose operand is the (n, d) points (the row pad to a tile
+    multiple), and the 2-D pads anywhere inside a loop body."""
+    outside = [e for e in _iter_eqns(jaxpr) if e.primitive.name == "pad"
+               and e.invars[0].aval.shape == (n, d)]
+    inside = [e.invars[0].aval.shape for body in _loop_bodies(jaxpr)
+              for e in _iter_eqns(body) if e.primitive.name == "pad"
+              and len(e.invars[0].aval.shape) == 2]
+    return outside, inside
+
+
+# n is not a tile multiple (the tile is 4096 here), so the rows need a pad
+_RAGGED_N = 16384 + 77
+
+
+def test_kmeans_pads_points_exactly_once():
+    """Acceptance: the Pallas prologue pads the (n, d) points to a tile
+    multiple ONCE per ``kmeans`` and hands the rows to every seeding round
+    and Lloyd iteration — exactly one pad of the points in the program,
+    none inside any loop body (XLA does not hoist a pad out of a while
+    loop, so a per-round pad would stay there)."""
+    from repro.core import engine as eng_mod
+    n, d, k = _RAGGED_N, 2, 4
+    pts = jnp.zeros((n, d), jnp.float32)
+    key = jax.random.PRNGKey(0)
+    jaxpr = jax.make_jaxpr(
+        lambda kk, pp: eng_mod.kmeans_points(kk, pp, k, None,
+                                             PallasBackend()))(key, pts)
+    outside, inside = _point_pads(jaxpr.jaxpr, n, d)
+    assert len(outside) == 1, outside
+    assert not inside, inside
+
+
+@pytest.mark.parametrize("phase", ["seed", "fit"])
+def test_seed_and_fit_pad_points_once_per_call(phase):
+    """The standalone seed and fit calls run their own prologue, which
+    pads the points once for all their rounds."""
+    from repro.core import engine as eng_mod
+    n, d, k = _RAGGED_N, 2, 4
+    pts = jnp.zeros((n, d), jnp.float32)
+    if phase == "seed":
+        fn = lambda pp: eng_mod.seed_points(  # noqa: E731
+            jax.random.PRNGKey(0), pp, k, None, PallasBackend())
+    else:
+        fn = lambda pp: eng_mod.fit_points(  # noqa: E731
+            pp, pp[:k], None, PallasBackend(), 5, 1e-6)
+    outside, inside = _point_pads(jax.make_jaxpr(fn)(pts).jaxpr, n, d)
+    assert len(outside) == 1, outside
+    assert not inside, inside
+
+
+@pytest.mark.parametrize("n", [4096, 4096 + 77])
+@pytest.mark.parametrize("backend", ["fused", "pallas"])
+def test_kmeans_shared_prologue_matches_two_phase_quality(backend, n):
+    """The one-prologue kmeans must cluster exactly as well as the
     historical seed-then-fit composition (same seeds under the cdf sampler:
-    min_d2 is tile-independent, so the draw is identical)."""
-    pts = _points(n=4096, d=2, k=8, seed=21)
+    min_d2 is tile-independent, so the draw is identical), and bitwise as
+    the fused backend does — also where n is not a tile multiple and the
+    pallas kernels stream the prologue's once-padded rows."""
+    pts = _points(n=n, d=2, k=8, seed=21)
     key = jax.random.PRNGKey(22)
-    eng = ClusterEngine("fused")
+    eng = ClusterEngine(backend)
     res = eng.kmeans(key, pts, 8, max_iters=15)
     seeds = eng.seed(key, pts, 8).centroids
     two = eng.fit(pts, seeds, max_iters=15)
     np.testing.assert_array_equal(np.asarray(res.centroids),
                                   np.asarray(two.centroids))
     assert float(res.inertia) == float(two.inertia)
+    fused = ClusterEngine("fused").kmeans(key, pts, 8, max_iters=15)
+    np.testing.assert_array_equal(np.asarray(res.centroids),
+                                  np.asarray(fused.centroids))
+    np.testing.assert_array_equal(np.asarray(res.assignment),
+                                  np.asarray(fused.assignment))
 
 
 def test_seed_reports_per_point_prune_telemetry():
