@@ -1,0 +1,12 @@
+"""Device self time of the Lloyd assignment kernels per job and per chip,
+in ms: summed over the chips' device planes, divided by the number of
+chips."""
+import jax
+
+from bench import kernel_names as kn
+
+
+def read(run):
+    t = run.trace.time(kernel=True, prefixes=kn.LLOYD)
+    return t * 1e3 / run.win["units"] / jax.device_count() if t > 0 \
+        else None

@@ -10,6 +10,8 @@ without a circular import; ``distributed`` re-exports for back-compat.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -180,11 +182,14 @@ def take_global_rows(points_local: jax.Array, global_idx: jax.Array,
     return jax.lax.psum(rows, axes)
 
 
-def take_global(points_local: jax.Array, global_idx: jax.Array, axes) -> jax.Array:
+def take_global(points_local: jax.Array, global_idx: jax.Array, axes,
+                n_local: Optional[int] = None) -> jax.Array:
     """Fetch the row `global_idx` of the sharded (axis-0) array: the owning shard
-    contributes the row, everyone else zeros, and one psum broadcasts it."""
+    contributes the row, everyone else zeros, and one psum broadcasts it.
+    ``n_local`` is each shard's row count when ``points_local`` carries pad
+    rows past it (the prologue's once-padded rows)."""
     me = axis_index(axes)
-    n_local = points_local.shape[0]
+    n_local = points_local.shape[0] if n_local is None else n_local
     owner = global_idx // n_local
     local = jnp.clip(global_idx - me * n_local, 0, n_local - 1)
     row = jnp.where(me == owner, points_local[local],

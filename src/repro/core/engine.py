@@ -683,11 +683,12 @@ class PallasBackend(Backend):
         """Pads the points to a tile multiple ONCE for the whole call
         (``RoundCache.rows``): the kernels stream those rows every round,
         so no round re-pads the (n, d) array. The prologue kernel streams
-        them too."""
+        them too. The copy goes a tile at a time (`pad_rows_blocked`), so
+        points in a column-major layout are never relaid out whole."""
         from repro.kernels import ops as kops
         n, d = points.shape
         tile = self.seed_tile(n, d, m)
-        rows = kops.pad_rows(points, -(-n // tile) * tile)
+        rows = kops.pad_rows_blocked(points, -(-n // tile) * tile, tile)
         if not with_bounds:
             return RoundCache(kops.point_norms(points), rows=rows)
         norms, centers, radii, center_d = kops.seed_prologue(
@@ -919,6 +920,19 @@ def _mesh_scoped(method):
         with jax.set_mesh(self.backend.mesh):
             return method(self, *args, **kwargs)
     return scoped
+
+
+def _shard_map(backend: MeshBackend, fn, *, in_specs, out_specs):
+    """``jax.shard_map`` of ``fn`` over the mesh backend's mesh. The Pallas
+    interpreter, which runs the kernels off the TPU, does not carry the
+    operands' varying mesh axes into the kernel body ("Primitive sub
+    requires varying manual axes to match"), so interpreted Pallas shards
+    run with that type check off; compiled kernels keep it."""
+    from repro.kernels import ops
+    interpreted = (isinstance(backend.local, PallasBackend)
+                   and ops.default_interpret())
+    return jax.shard_map(fn, mesh=backend.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=not interpreted)
 
 
 # ---------------------------------------------------------------------------
@@ -1589,151 +1603,15 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
     axes = backend.axes
 
     def local_fn(kk, pp):
-        pts = pp.astype(jnp.float32)
-        n_local, d = pts.shape
-        stream = _stream_of(pts, precision)
-        cache = _stream_cache(backend.prologue(pts, with_bounds=bound_gate),
-                              stream)
-        tile = backend.seed_tile(n_local, d)
-        n_tiles = -(-n_local // tile)
-        if bound_gate:
-            init_state = BoundState(
-                backend.pvary(jnp.zeros((n_tiles,), jnp.float32)),
-                backend.pvary(jnp.full((n_tiles,), jnp.inf, jnp.float32)))
-        else:
-            init_state = None
-        first_fn = lambda k0: collectives.dist_gumbel_choice(  # noqa: E731
-            k0, jnp.zeros((n_local,), jnp.float32), axes)
-        take_fn = lambda i: collectives.take_global(pts, i, axes)  # noqa: E731
-        init_min_d2 = backend.pvary(
-            jnp.full((n_local,), jnp.inf, jnp.float32))
-
-        if sampler == "rejection":
-            hier = proposal == "hier"
-            tps_ = backend.tiles_per_super(n_tiles)
-            tiny = jnp.finfo(jnp.float32).tiny
-            # shard-local per-tile row counts (mesh seeding is unweighted)
-            tileW = jnp.full((n_tiles,), float(tile), jnp.float32) \
-                .at[n_tiles - 1].set(float(n_local - (n_tiles - 1) * tile))
-
-            def prep_fn(partials, pending, count):
-                # shard-local tightening from the shard-local prologue
-                # balls; the tightened-tile count is psum'd so the
-                # telemetry counter stays replicated like props/accs
-                if cache.centers is not None:
-                    cap = backend.tile_cap(cache.centers, cache.radii,
-                                           pending, count)
-                else:
-                    cap = jnp.full((n_tiles,), jnp.inf, jnp.float32)
-                capw = cap * tileW
-                ph = jnp.where(capw < partials, capw, partials)
-                tightb = ph < partials
-                tight_n = jax.lax.psum(jnp.sum(tightb.astype(jnp.int32)),
-                                       axes)
-                return (ph, cap, tightb, count), tight_n
-
-            def propose_hier(kj, weight, partials, pstate):
-                # count is REPLICATED (it is carried from replicated accept
-                # decisions), so every shard takes the same branch and the
-                # collectives inside stay aligned. Fresh-envelope rounds
-                # (count == 0 — always, at refresh_block=1) route through
-                # the flat draw so its key schedule, and hence the
-                # sampler='tiled' bitwise pin, is preserved.
-                ph, cap, tightb, count = pstate
-                return jax.lax.cond(
-                    count > 0,
-                    lambda _: collectives.dist_hier_choice(
-                        kj, weight, ph, tile, tps_, axes,
-                        cap=cap, tight=tightb),
-                    lambda _: collectives.dist_tiled_choice(
-                        kj, weight, partials, tile, axes),
-                    None)
-
-            def pq_fn(gidx, weight, pending, count, pstate):
-                # the OWNER shard evaluates the drawn row's exact current
-                # weight p and envelope weight q; one (2,)-fp32 psum
-                # broadcasts them, keeping the accept decision replicated.
-                # Tightened tiles price the draw as the capped window the
-                # hier proposal drew from (see seed_points' pq_fn twin)
-                me = collectives.axis_index(axes)
-                local = jnp.clip(gidx - me * n_local, 0, n_local - 1)
-                rd2 = backend.row_min_d2(pts, local, pending, count)
-                if hier:
-                    ph, cap, tightb, _ = pstate
-                    t = local // tile
-                    li = local - t * tile
-                    win = sampling.tile_window(weight, t, tile)
-                    cwin = jnp.where(cap[t] < win, cap[t], win)
-                    s_t = jnp.cumsum(cwin)[tile - 1]
-                    q_loc = jnp.where(
-                        tightb[t],
-                        cwin[li] * (ph[t] / jnp.maximum(s_t, tiny)),
-                        weight[local])
-                else:
-                    q_loc = weight[local]
-                vec = jnp.where(me == gidx // n_local,
-                                jnp.stack([jnp.minimum(q_loc, rd2), q_loc]),
-                                jnp.zeros((2,), jnp.float32))
-                pq = jax.lax.psum(vec, axes)
-                return pq[0], pq[1]
-
-            if hier:
-                propose_fn = propose_hier
-                fallback_fn = lambda kf, weight, partials: \
-                    collectives.dist_hier_choice(kf, weight, partials,
-                                                 tile, tps_, axes)
-            else:
-                propose_fn = lambda kj, weight, partials, pstate: \
-                    collectives.dist_tiled_choice(kj, weight, partials,
-                                                  tile, axes)
-                fallback_fn = lambda kf, weight, partials: \
-                    collectives.dist_tiled_choice(kf, weight, partials,
-                                                  tile, axes)
-
-            return _seed_rejection_loop(
-                kk, pts, k, None,
-                round_fn=lambda c, md, st: backend.seed_round(
-                    stream, c.astype(stream.dtype), md, None, cache=cache,
-                    state=st),
-                first_fn=first_fn, take_fn=take_fn,
-                propose_fn=propose_fn,
-                pq_fn=pq_fn,
-                fallback_fn=fallback_fn,
-                prep_fn=prep_fn if hier else None, hier=hier,
-                n_tiles=n_tiles,
-                all_tiles=n_tiles * collectives.axis_size(axes),
-                refresh_block=refresh_block, max_attempts=max_attempts,
-                init_min_d2=init_min_d2, init_state=init_state,
-                init_partials=backend.pvary(
-                    jnp.zeros((n_tiles,), jnp.float32)),
-                tile=tile, guard=guard, fault=fault,
-                allreduce=lambda x: jax.lax.psum(x, axes))
-
-        if sampler == "tiled":
-            def sample_fn(ks, weight, partials):
-                return collectives.dist_tiled_choice(ks, weight, partials,
-                                                     tile, axes)
-        else:
-            def sample_fn(ks, weight, partials):
-                return collectives.dist_gumbel_choice(
-                    ks, sampling.safe_log(weight), axes)
-
-        return _seed_loop(
-            kk, pts, k, None,
-            round_fn=lambda c, md, st: backend.seed_round(
-                stream, c.astype(stream.dtype)[None, :], md, None,
-                cache=cache, state=st),
-            first_fn=first_fn,
-            sample_fn=sample_fn,
-            take_fn=take_fn,
-            init_min_d2=init_min_d2,
-            init_state=init_state,
-            guard=guard, tile=tile, fault=fault,
-        )
+        return _seed_mesh_local(
+            kk, pp.astype(jnp.float32), k, backend, sampler,
+            precision=precision, bound_gate=bound_gate,
+            refresh_block=refresh_block, proposal=proposal,
+            max_attempts=max_attempts, guard=guard, fault=fault)
 
     if sampler == "rejection":
-        mapped = jax.shard_map(
-            local_fn, mesh=backend.mesh,
+        mapped = _shard_map(
+            backend, local_fn,
             in_specs=(P(), P(axes)),
             out_specs=(P(), P(), P(axes), P(), P(), P(), P(), P(),
                        P(), P()))
@@ -1745,8 +1623,8 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
                               recovered=rec if guard else None,
                               tightened=tights, supers=sups)
 
-    mapped = jax.shard_map(
-        local_fn, mesh=backend.mesh,
+    mapped = _shard_map(
+        backend, local_fn,
         in_specs=(P(), P(axes)),
         out_specs=(P(), P(), P(axes), P(), P(), P()))
     centroids, indices, min_d2, skips, prunes, rec = mapped(key, points)
@@ -1754,6 +1632,162 @@ def _seed_mesh(key, points, k, weights, backend: MeshBackend,
                           skips if bound_gate else None,
                           prunes if bound_gate else None,
                           recovered=rec if guard else None)
+
+
+def _seed_mesh_local(kk, pts, k, backend: MeshBackend, sampler: str, *,
+                     precision: str, bound_gate: bool, refresh_block: int,
+                     proposal: str, max_attempts: int, guard: bool,
+                     fault=None, cache: Optional[RoundCache] = None):
+    """One shard's side of `_seed_mesh`, run inside ``shard_map`` on the
+    shard's fp32 rows ``pts``: returns the seeding loop's raw outputs
+    (centroids first). A precomputed ``cache`` (``kmeans_points``) stands in
+    for this call's own prologue."""
+    axes = backend.axes
+    n_local, d = pts.shape
+    stream = _stream_of(pts, precision)
+    if cache is None:
+        cache = backend.prologue(pts, with_bounds=bound_gate)
+    # the drawn rows come from the fp32 padded rows when the prologue made
+    # them, so the loop holds no second copy of the shard in another layout
+    src = pts if cache.rows is None else cache.rows
+    cache = _stream_cache(cache, stream)
+    tile = backend.seed_tile(n_local, d)
+    n_tiles = -(-n_local // tile)
+    if bound_gate:
+        init_state = BoundState(
+            backend.pvary(jnp.zeros((n_tiles,), jnp.float32)),
+            backend.pvary(jnp.full((n_tiles,), jnp.inf, jnp.float32)))
+    else:
+        init_state = None
+    first_fn = lambda k0: collectives.dist_gumbel_choice(  # noqa: E731
+        k0, jnp.zeros((n_local,), jnp.float32), axes)
+    take_fn = lambda i: collectives.take_global(  # noqa: E731
+        src, i, axes, n_local)
+    init_min_d2 = backend.pvary(
+        jnp.full((n_local,), jnp.inf, jnp.float32))
+
+    if sampler == "rejection":
+        hier = proposal == "hier"
+        tps_ = backend.tiles_per_super(n_tiles)
+        tiny = jnp.finfo(jnp.float32).tiny
+        # shard-local per-tile row counts (mesh seeding is unweighted)
+        tileW = jnp.full((n_tiles,), float(tile), jnp.float32) \
+            .at[n_tiles - 1].set(float(n_local - (n_tiles - 1) * tile))
+
+        def prep_fn(partials, pending, count):
+            # shard-local tightening from the shard-local prologue
+            # balls; the tightened-tile count is psum'd so the
+            # telemetry counter stays replicated like props/accs
+            if cache.centers is not None:
+                cap = backend.tile_cap(cache.centers, cache.radii,
+                                       pending, count)
+            else:
+                cap = jnp.full((n_tiles,), jnp.inf, jnp.float32)
+            capw = cap * tileW
+            ph = jnp.where(capw < partials, capw, partials)
+            tightb = ph < partials
+            tight_n = jax.lax.psum(jnp.sum(tightb.astype(jnp.int32)),
+                                   axes)
+            return (ph, cap, tightb, count), tight_n
+
+        def propose_hier(kj, weight, partials, pstate):
+            # count is REPLICATED (it is carried from replicated accept
+            # decisions), so every shard takes the same branch and the
+            # collectives inside stay aligned. Fresh-envelope rounds
+            # (count == 0 — always, at refresh_block=1) route through
+            # the flat draw so its key schedule, and hence the
+            # sampler='tiled' bitwise pin, is preserved.
+            ph, cap, tightb, count = pstate
+            return jax.lax.cond(
+                count > 0,
+                lambda _: collectives.dist_hier_choice(
+                    kj, weight, ph, tile, tps_, axes,
+                    cap=cap, tight=tightb),
+                lambda _: collectives.dist_tiled_choice(
+                    kj, weight, partials, tile, axes),
+                None)
+
+        def pq_fn(gidx, weight, pending, count, pstate):
+            # the OWNER shard evaluates the drawn row's exact current
+            # weight p and envelope weight q; one (2,)-fp32 psum
+            # broadcasts them, keeping the accept decision replicated.
+            # Tightened tiles price the draw as the capped window the
+            # hier proposal drew from (see seed_points' pq_fn twin)
+            me = collectives.axis_index(axes)
+            local = jnp.clip(gidx - me * n_local, 0, n_local - 1)
+            rd2 = backend.row_min_d2(pts, local, pending, count)
+            if hier:
+                ph, cap, tightb, _ = pstate
+                t = local // tile
+                li = local - t * tile
+                win = sampling.tile_window(weight, t, tile)
+                cwin = jnp.where(cap[t] < win, cap[t], win)
+                s_t = jnp.cumsum(cwin)[tile - 1]
+                q_loc = jnp.where(
+                    tightb[t],
+                    cwin[li] * (ph[t] / jnp.maximum(s_t, tiny)),
+                    weight[local])
+            else:
+                q_loc = weight[local]
+            vec = jnp.where(me == gidx // n_local,
+                            jnp.stack([jnp.minimum(q_loc, rd2), q_loc]),
+                            jnp.zeros((2,), jnp.float32))
+            pq = jax.lax.psum(vec, axes)
+            return pq[0], pq[1]
+
+        if hier:
+            propose_fn = propose_hier
+            fallback_fn = lambda kf, weight, partials: \
+                collectives.dist_hier_choice(kf, weight, partials,
+                                             tile, tps_, axes)
+        else:
+            propose_fn = lambda kj, weight, partials, pstate: \
+                collectives.dist_tiled_choice(kj, weight, partials,
+                                              tile, axes)
+            fallback_fn = lambda kf, weight, partials: \
+                collectives.dist_tiled_choice(kf, weight, partials,
+                                              tile, axes)
+
+        return _seed_rejection_loop(
+            kk, pts, k, None,
+            round_fn=lambda c, md, st: backend.seed_round(
+                stream, c.astype(stream.dtype), md, None, cache=cache,
+                state=st),
+            first_fn=first_fn, take_fn=take_fn,
+            propose_fn=propose_fn,
+            pq_fn=pq_fn,
+            fallback_fn=fallback_fn,
+            prep_fn=prep_fn if hier else None, hier=hier,
+            n_tiles=n_tiles,
+            all_tiles=n_tiles * collectives.axis_size(axes),
+            refresh_block=refresh_block, max_attempts=max_attempts,
+            init_min_d2=init_min_d2, init_state=init_state,
+            init_partials=backend.pvary(
+                jnp.zeros((n_tiles,), jnp.float32)),
+            tile=tile, guard=guard, fault=fault,
+            allreduce=lambda x: jax.lax.psum(x, axes))
+
+    if sampler == "tiled":
+        def sample_fn(ks, weight, partials):
+            return collectives.dist_tiled_choice(ks, weight, partials,
+                                                 tile, axes)
+    else:
+        def sample_fn(ks, weight, partials):
+            return collectives.dist_gumbel_choice(
+                ks, sampling.safe_log(weight), axes)
+
+    return _seed_loop(
+        kk, pts, k, None,
+        round_fn=lambda c, md, st: backend.seed_round(
+            stream, c.astype(stream.dtype)[None, :], md, None,
+            cache=cache, state=st),
+        first_fn=first_fn,
+        sample_fn=sample_fn,
+        take_fn=take_fn,
+        init_min_d2=init_min_d2,
+        init_state=init_state,
+        guard=guard, tile=tile, fault=fault,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1964,6 +1998,12 @@ def _fit_loop(pts, init_centroids, w, backend: Backend, max_iters, tol,
     return cents, a, inertia, i, None, None, None
 
 
+def _check_empty(empty: str):
+    if empty not in ("keep", "reseed"):
+        raise ValueError(f"unknown empty-cluster policy {empty!r}; "
+                         "expected 'keep' or 'reseed'")
+
+
 def fit_points(points: jax.Array, init_centroids: jax.Array,
                weights: Optional[jax.Array], backend: Backend,
                max_iters: int, tol: float, empty: str = "keep",
@@ -1976,9 +2016,7 @@ def fit_points(points: jax.Array, init_centroids: jax.Array,
     optional precomputed prologue (``kmeans_points`` shares one across the
     seed and fit phases). ``guard`` turns on the in-flight corruption
     detector (gated unweighted fits only — see ``_fit_gated_parts``)."""
-    if empty not in ("keep", "reseed"):
-        raise ValueError(f"unknown empty-cluster policy {empty!r}; "
-                         "expected 'keep' or 'reseed'")
+    _check_empty(empty)
     if backend.distributed:
         return _fit_mesh(points, init_centroids, weights, backend,
                          max_iters, tol, empty, precision, bound_gate,
@@ -2015,8 +2053,8 @@ def _fit_mesh(points, init_centroids, weights, backend: MeshBackend,
     del gated  # the skips/prunes/recovered leaves are replicated when
     #            present, absent otherwise; P() is a valid prefix spec for
     #            the empty (None) subtree too
-    mapped = jax.shard_map(
-        local_fn, mesh=backend.mesh,
+    mapped = _shard_map(
+        backend, local_fn,
         in_specs=in_specs,
         out_specs=(P(), P(axes), P(), P(), P(), P(), P()))
     cents, a, inertia, i, skips, prunes, rec = mapped(*args)
@@ -2037,24 +2075,65 @@ def kmeans_points(key: jax.Array, points: jax.Array, k: int,
 
     The seed phase and the fit phase historically each ran
     ``backend.prologue`` over the same points (two full O(n·d) streaming
-    passes, two norm computations). Here the backend's ``tile_m`` is pinned
-    to k so both phases agree on one tile geometry, the prologue runs once,
-    and the same RoundCache threads through ``seed_points`` and
-    ``fit_points`` — a jaxpr test pins that the whole kmeans program
-    computes the row norms exactly once. Local backends only (the mesh path
-    keeps per-phase prologues inside shard_map)."""
-    be = dataclasses.replace(backend, tile_m=k)
-    compute_dtype = jnp.promote_types(points.dtype, jnp.float32)
-    pts = points.astype(compute_dtype)
+    passes, two norm computations). Here the (local) backend's ``tile_m``
+    is pinned to k so both phases agree on one tile geometry, the prologue
+    runs once, and the same RoundCache threads through the seeding loop and
+    the Lloyd loop — a jaxpr test pins that the whole kmeans program
+    computes the row norms exactly once. On a mesh backend the same body
+    runs once per shard inside ONE ``shard_map``: one prologue and one pad
+    of each shard per call, the distributed seeding of ``_seed_mesh`` and
+    the sharded Lloyd loop of ``_fit_mesh`` in one program, computing what
+    the two calls compute (bitwise on the CPU, tests/test_mesh_kmeans.py)."""
+    _check_empty(empty)
+    body = functools.partial(
+        _kmeans_shared, k=k, sampler=sampler, max_iters=max_iters, tol=tol,
+        empty=empty, precision=precision, bound_gate=bound_gate,
+        refresh_block=refresh_block, proposal=proposal,
+        max_attempts=max_attempts, guard=guard)
+    if not backend.distributed:
+        return body(key, points, weights, dataclasses.replace(backend,
+                                                              tile_m=k))
+    if weights is not None:
+        raise NotImplementedError("mesh seeding does not take weights")
+    be = dataclasses.replace(
+        backend, local=dataclasses.replace(backend.local, tile_m=k))
+
+    def local_fn(kk, pp):
+        r = body(kk, pp, None, be)
+        return (r.centroids, r.assignment, r.inertia, r.n_iters, r.skipped,
+                r.pruned, r.recovered)
+
+    mapped = _shard_map(
+        be, local_fn, in_specs=(P(), P(be.axes)),
+        out_specs=(P(), P(be.axes), P(), P(), P(), P(), P()))
+    cents, a, inertia, i, skips, prunes, rec = mapped(key, points)
+    return LloydResult(cents, a, inertia, i, skips, prunes, recovered=rec)
+
+
+def _kmeans_shared(key, points, weights, be: Backend, *, k, sampler,
+                   max_iters, tol, empty, precision, bound_gate,
+                   refresh_block, proposal, max_attempts,
+                   guard) -> LloydResult:
+    """`kmeans_points`' body over the rows it is given: all of them on a
+    local backend, one shard's inside ``shard_map`` on a mesh backend."""
+    pts = points.astype(jnp.promote_types(points.dtype, jnp.float32))
     cache = be.prologue(pts, m=k, with_bounds=bound_gate)
-    seeds = seed_points(key, pts, k, weights, be, sampler,
-                        precision=precision, bound_gate=bound_gate,
-                        cache=cache, refresh_block=refresh_block,
-                        proposal=proposal, max_attempts=max_attempts,
-                        guard=guard)
-    res = fit_points(pts, seeds.centroids, weights, be, max_iters, tol,
-                     empty, precision, bound_gate, cache=cache, guard=guard)
-    return res._replace(centroids=res.centroids.astype(points.dtype))
+    seed_kw = dict(precision=precision, bound_gate=bound_gate, cache=cache,
+                   refresh_block=refresh_block, proposal=proposal,
+                   max_attempts=max_attempts, guard=guard)
+    if be.distributed:
+        # the seeds pass through the points' dtype, as they do between
+        # the mesh `seed` and `fit` calls
+        seeds = _seed_mesh_local(key, pts, k, be, sampler,
+                                 **seed_kw)[0].astype(points.dtype)
+    else:
+        seeds = seed_points(key, pts, k, weights, be, sampler,
+                            **seed_kw).centroids
+    cents, a, inertia, i, skips, prunes, rec = _fit_loop(
+        pts, seeds, weights, be, max_iters, tol, empty, precision,
+        bound_gate, cache, guard=guard)
+    return LloydResult(cents.astype(points.dtype), a, inertia, i, skips,
+                       prunes, recovered=rec)
 
 
 # ---------------------------------------------------------------------------
@@ -2555,9 +2634,10 @@ class ClusterEngine:
         ``order`` reorders the rows ONCE up front (see `fit`): both the
         seeding scan and every Lloyd iteration then see the tile-coherent
         layout, and the returned assignment is mapped back to the caller's
-        row order. On local backends the kmeans++ path runs as ONE compiled
-        call sharing a single prologue (norms + tile balls computed once for
-        both phases — see ``kmeans_points``)."""
+        row order. The kmeans++ path runs as ONE compiled call sharing a
+        single prologue (norms, tile balls and the padded rows computed once
+        for both phases; once per shard on a mesh backend — see
+        ``kmeans_points``)."""
         points = guards.guard_points(points, self.validate)
         weights = guards.guard_weights(weights, points.shape[0],
                                        self.validate)
@@ -2568,9 +2648,8 @@ class ClusterEngine:
         sampler, refresh_block, proposal = self._tune_sampler(
             sampler, refresh_block, rec, proposal)
         points, weights, perm, inv = self._order_in(points, order, weights)
-        if init == "kmeans++" and not self.backend.distributed:
-            n = points.shape[0]
-            guards.check_shape(k, n)
+        if init == "kmeans++":
+            guards.check_shape(k, points.shape[0])
             res = self._run(lambda be: self._launch(
                 _kmeans_jit, key, points, weights, k, be, sampler, max_iters,
                 float(tol), empty, self.precision, self.bounds,
@@ -2579,13 +2658,7 @@ class ClusterEngine:
             if rec is not None:
                 res = res._replace(tune=rec)
             return self._order_out(res, perm, inv)
-        if init == "kmeans++":
-            seeds = self.seed(key, points, k, weights=weights,
-                              sampler=sampler,
-                              refresh_block=refresh_block,
-                              proposal=proposal,
-                              max_attempts=max_attempts).centroids
-        elif init == "kmeans||":
+        if init == "kmeans||":
             if self.backend.distributed:
                 raise NotImplementedError("k-means|| init runs on a local "
                                           "backend; seed locally, fit on mesh")

@@ -56,9 +56,10 @@ The jitted wrappers keep the callers' ``(n,)``/``(n_tiles,)`` shapes and do
 the padding and reshaping outside the kernels. The round wrappers take the
 valid row count ``n`` from their per-point vectors (``min_d2`` / ``norms``),
 not from the points: the engine's prologue pads the ``(n, d)`` points to a
-tile multiple ONCE per job (``core.bounds.RoundCache.rows``) and every round
-streams those rows as they are (`pad_rows` is then a no-op); callers with
-plain ``(n, d)`` points get the pad on each call.
+tile multiple ONCE per job (``core.bounds.RoundCache.rows``, built by
+`pad_rows_blocked`) and every round streams those rows as they are
+(`pad_rows` is then a no-op); callers with plain ``(n, d)`` points get the
+pad on each call.
 
 Raw kernels take ``interpret`` EXPLICITLY: ``kernels.ops`` is the single
 place the on-TPU/off-TPU default is chosen — calling a raw kernel without it
@@ -121,6 +122,32 @@ def pad_rows(points: jax.Array, n_pad: int) -> jax.Array:
     if extra <= 0:
         return points
     return jnp.pad(points, [(0, 0)] * (points.ndim - 2) + [(0, extra), (0, 0)])
+
+
+@functools.partial(jax.jit, static_argnames=("n_pad", "block"))
+def pad_rows_blocked(points: jax.Array, n_pad: int, block: int) -> jax.Array:
+    """`pad_rows` of (n, d) points, copied ``block`` rows at a time into
+    the zero-filled (n_pad, d) rows: the points are read as they lie. The
+    kernels stream row-major rows, while a chip's default layout for some
+    (n, d) shapes is column-major ((2,025,000, 784) on a v5e); a plain pad
+    of such points makes XLA relayout all of them first, and the relayout
+    copy, the padded rows and the points do not fit one chip together. Here
+    each block is relaid out on its own. The last block ends at row n."""
+    n, d = points.shape
+    if n >= n_pad or n < block:
+        return pad_rows(points, n_pad)
+    out = jnp.zeros((n_pad, d), points.dtype)
+    vma = tuple(jax.typeof(points).vma)
+    if vma:     # inside shard_map: the rows vary over the mesh as the points do
+        out = jax.lax.pcast(out, vma, to="varying")
+
+    def copy(i, rows):
+        start = jnp.minimum(i * block, n - block)
+        return jax.lax.dynamic_update_slice(
+            rows, jax.lax.dynamic_slice(points, (start, 0), (block, d)),
+            (start, 0))
+
+    return jax.lax.fori_loop(0, -(-n // block), copy, out)
 
 
 def as_row(v: jax.Array, pad: int, fill=0.0) -> jax.Array:

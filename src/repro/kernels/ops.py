@@ -24,7 +24,7 @@ same for the gated batch-grid kernel (per-problem compacted tile maps).
 
 Row padding: the round wrappers take the valid row count from their
 per-point vectors, so ``points`` may be the engine prologue's once-padded
-rows (``core.bounds.RoundCache.rows``, built by `pad_rows`; the cached
+rows (``core.bounds.RoundCache.rows``, built by `pad_rows_blocked`; the cached
 ``norms`` then come with them), which stream as they are. Plain ``(n, d)``
 points are padded inside the kernel wrapper on each call.
 """
@@ -41,8 +41,9 @@ from repro.kernels.kmeans_distance import (
     distance_min_update_gated_pallas,
     distance_min_update_gated_batched_pallas, distance_min_update_pallas,
     row_min_d2_pallas, seed_prologue_pallas, tile_cap_pallas)
-from repro.kernels.kmeans_distance import pad_rows  # noqa: F401  (re-exported:
-#   the engine's prologue pads the points once per job with it)
+from repro.kernels.kmeans_distance import (  # noqa: F401  (re-exported:
+    pad_rows, pad_rows_blocked)  # the engine's prologue pads the points
+#   once per job with the blocked copy)
 from repro.core.bounds import point_norms  # noqa: F401  (re-exported: the
 #   cached-norm input the kernels stream; wrappers compute it on the fly
 #   when the caller has no prologue cache)

@@ -645,14 +645,21 @@ def test_kmeans_computes_point_norms_exactly_once():
             "no kmeans loop may recompute ||x||^2"
 
 
+def _is_row_pad(eqn):
+    """A pad of 2-D rows: a ``pad``, or the prologue's blocked copy into
+    padded rows (``ops.pad_rows_blocked``, one jitted call)."""
+    return (eqn.primitive.name == "pad"
+            or eqn.params.get("name") == "pad_rows_blocked") \
+        and len(eqn.invars[0].aval.shape) == 2
+
+
 def _point_pads(jaxpr, n, d):
-    """pad eqns whose operand is the (n, d) points (the row pad to a tile
-    multiple), and the 2-D pads anywhere inside a loop body."""
-    outside = [e for e in _iter_eqns(jaxpr) if e.primitive.name == "pad"
+    """Row pads whose operand is the (n, d) points (the pad to a tile
+    multiple), and the row pads anywhere inside a loop body."""
+    outside = [e for e in _iter_eqns(jaxpr) if _is_row_pad(e)
                and e.invars[0].aval.shape == (n, d)]
     inside = [e.invars[0].aval.shape for body in _loop_bodies(jaxpr)
-              for e in _iter_eqns(body) if e.primitive.name == "pad"
-              and len(e.invars[0].aval.shape) == 2]
+              for e in _iter_eqns(body) if _is_row_pad(e)]
     return outside, inside
 
 
@@ -676,6 +683,20 @@ def test_kmeans_pads_points_exactly_once():
     outside, inside = _point_pads(jaxpr.jaxpr, n, d)
     assert len(outside) == 1, outside
     assert not inside, inside
+
+
+@pytest.mark.parametrize("n", [4096 * 3, _RAGGED_N, 4096 - 5])
+def test_blocked_row_pad_equals_pad(n):
+    """The prologue's blocked copy gives the rows a plain pad gives: the
+    points, then zero rows up to the tile multiple, for n a tile multiple,
+    n ragged (the last block ends at row n) and n below one tile."""
+    from repro.kernels import ops as kops
+    tile, d = 4096, 3
+    pts = jax.random.normal(jax.random.PRNGKey(n), (n, d), jnp.float32)
+    n_pad = -(-n // tile) * tile
+    np.testing.assert_array_equal(
+        np.asarray(kops.pad_rows_blocked(pts, n_pad, tile)),
+        np.asarray(kops.pad_rows(pts, n_pad)))
 
 
 @pytest.mark.parametrize("phase", ["seed", "fit"])
