@@ -123,6 +123,14 @@ class RoundCache(NamedTuple):
     they are (pad rows are masked by the valid count n). They are the points
     themselves when n is already a tile multiple, and ``None`` on backends
     that pad nothing (reference, fused).
+
+    ``cols`` are the same padded points coordinate-major, ``(d, n_tiles *
+    tile)`` with the points on lanes: at small d (``kernels.ops.streams_cols``,
+    d <= 32) the Pallas prologue builds them once per call from
+    ``points.T``, and the plain and gated seeding-round kernels stream them
+    in place of ``rows`` (a row tile pads d to 128 lanes). The prologue
+    kernel and the Lloyd kernels still stream ``rows``. ``None`` at larger d
+    and on the other backends.
     """
 
     norms: jax.Array                       # (n,) fp32 ||x||²
@@ -131,6 +139,8 @@ class RoundCache(NamedTuple):
     center_d: Optional[jax.Array] = None   # (n,) fp32 d(x, tile center) —
                                            # the per-point seeding bound
     rows: Optional[jax.Array] = None       # (n_tiles * tile, d) padded points
+    cols: Optional[jax.Array] = None       # (d, n_tiles * tile) the same,
+                                           # coordinate-major (small d)
 
 
 class BoundState(NamedTuple):

@@ -9,6 +9,8 @@ fused and Pallas results bitwise comparable on one compiler.
 The layout is the kernels' (see ``kernels.kmeans_distance``): a tile's
 distances are ``(k, block_n)`` — centroids on sublanes, points on lanes —
 so every per-point reduction over centroids yields a ``(1, block_n)`` row.
+A point tile comes as ``(block_n, d)`` rows or, in the small-d seeding
+rounds, coordinate-major as ``(d, block_n)`` (`tile_d2_min_cols`).
 The dots run at ``Precision.HIGHEST``: the TPU's default fp32 matmul rounds
 its operands to bf16, whose error the cancellation in the matmul form would
 amplify into wrong assignments.
@@ -21,15 +23,18 @@ import jax.numpy as jnp
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def tile_d2(x_raw, c_raw, xn):
+def tile_d2(x_raw, c_raw, xn, *, cols: bool = False):
     """(k, block_n) matmul-form D^2 of one point tile.
 
     ``x_raw`` (block_n, d) / ``c_raw`` (k, d) keep their input dtype into the
     MXU (bf16 streams at half width) with fp32 accumulation; ``xn`` is the
-    cached fp32 ``||x||^2`` row (1, block_n)."""
+    cached fp32 ``||x||^2`` row (1, block_n). ``cols=True`` takes the tile
+    coordinate-major, ``x_raw`` (d, block_n) with the points on lanes: the
+    dot then contracts d on the sublane axis."""
     cf = c_raw.astype(jnp.float32)
     cn = jnp.sum(cf * cf, axis=1, keepdims=True)        # (k, 1)
-    dots = jax.lax.dot_general(c_raw, x_raw, (((1,), (1,)), ((), ())),
+    dots = jax.lax.dot_general(c_raw, x_raw,
+                               (((1,), (0 if cols else 1,)), ((), ())),
                                preferred_element_type=jnp.float32,
                                precision=HIGHEST)       # (k, block_n)
     return jnp.maximum(xn - 2.0 * dots + cn, 0.0)
@@ -39,6 +44,15 @@ def tile_d2_min(x_raw, c_raw, xn):
     """(1, block_n) min over the centroid block of `tile_d2` (the seeding
     round's fold)."""
     return jnp.min(tile_d2(x_raw, c_raw, xn), axis=0, keepdims=True)
+
+
+def tile_d2_min_cols(xt_raw, c_raw, xn):
+    """`tile_d2_min` of a coordinate-major tile ``xt_raw`` (d, block_n): the
+    same formula, precision and work; only the operand's layout differs (the
+    small-d seeding rounds stream the points this way, see
+    ``kernels.kmeans_distance``)."""
+    return jnp.min(tile_d2(xt_raw, c_raw, xn, cols=True), axis=0,
+                   keepdims=True)
 
 
 def nearest(d2):

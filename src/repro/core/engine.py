@@ -44,9 +44,10 @@ A ``Backend`` provides exactly two round primitives:
 plus ``prologue(points, m=, with_bounds=)`` — the once-per-call pass that
 builds the RoundCache (the Pallas backend fuses it into one streaming
 kernel, and pads the points to a tile multiple there, once, for every
-round: ``RoundCache.rows``). Mixed precision: the engine streams
-points/centroids as bf16 when ``precision='bf16'`` while norms,
-accumulators, min_d2 and the bound state stay fp32.
+round: ``RoundCache.rows``, and at small d ``RoundCache.cols``, the same
+points coordinate-major for the seeding rounds). Mixed precision: the
+engine streams points/centroids as bf16 when ``precision='bf16'`` while
+norms, accumulators, min_d2 and the bound state stay fp32.
 
 plus two trivial hooks (``allreduce``, ``pvary``) that are identity on a
 single device and psum/pcast on a mesh. Every algorithm above is written once
@@ -663,11 +664,13 @@ def _rows(points, cache: Optional[RoundCache]):
 
 
 def _stream_cache(cache: Optional[RoundCache], stream) -> Optional[RoundCache]:
-    """Once per phase, outside the loop: the padded rows in the dtype the
-    rounds stream (a bf16 copy under precision='bf16'; pad rows stay 0)."""
+    """Once per phase, outside the loop: the padded rows (and the small-d
+    columns) in the dtype the rounds stream (a bf16 copy under
+    precision='bf16'; pad rows stay 0)."""
     if cache is None or cache.rows is None or cache.rows.dtype == stream.dtype:
         return cache
-    return cache._replace(rows=cache.rows.astype(stream.dtype))
+    cols = None if cache.cols is None else cache.cols.astype(stream.dtype)
+    return cache._replace(rows=cache.rows.astype(stream.dtype), cols=cols)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -684,16 +687,21 @@ class PallasBackend(Backend):
         (``RoundCache.rows``): the kernels stream those rows every round,
         so no round re-pads the (n, d) array. The prologue kernel streams
         them too. The copy goes a tile at a time (`pad_rows_blocked`), so
-        points in a column-major layout are never relaid out whole."""
+        points in a column-major layout are never relaid out whole. At small
+        d (`kops.streams_cols`) it also pads ``points.T`` once
+        (``RoundCache.cols``, a bitcast plus a pad on the chip's default
+        layout for (n, 2)), which the seeding rounds stream instead."""
         from repro.kernels import ops as kops
         n, d = points.shape
         tile = self.seed_tile(n, d, m)
-        rows = kops.pad_rows_blocked(points, -(-n // tile) * tile, tile)
+        n_pad = -(-n // tile) * tile
+        rows = kops.pad_rows_blocked(points, n_pad, tile)
+        cols = kops.pad_cols(points.T, n_pad) if kops.streams_cols(d) else None
         if not with_bounds:
-            return RoundCache(kops.point_norms(points), rows=rows)
+            return RoundCache(kops.point_norms(points), rows=rows, cols=cols)
         norms, centers, radii, center_d = kops.seed_prologue(
             points, block_n=tile, rows=rows)
-        return RoundCache(norms, centers, radii, center_d, rows)
+        return RoundCache(norms, centers, radii, center_d, rows, cols)
 
     def seed_round(self, points, c_new, min_d2, weights, *, cache=None,
                    state=None):
@@ -706,6 +714,8 @@ class PallasBackend(Backend):
         tile = self.seed_tile(n, d, m)
         norms = None if cache is None else cache.norms
         rows = _rows(points, cache)
+        # small d: the coordinate-major copy, points on lanes
+        cols = None if cache is None else cache.cols
         if (state is not None and weights is None and cache is not None
                 and cache.centers is not None):
             # cache.norms is always populated (and always fp32 — never derive
@@ -717,14 +727,14 @@ class PallasBackend(Backend):
                 kops.distance_min_update_gated(
                     rows, c_new, min_d2, norms, cache.center_d, dc, margin,
                     state.partials, state.tile_max, active, block_n=tile,
-                    resident_centroids=self.resident)
+                    resident_centroids=self.resident, cols=cols)
             # per-tile counts are fp32 (kernel vectors); cast BEFORE the
             # reduction so the counter stays exact past 2^24 points
             return SeedRound(md, jnp.sum(partials), partials, tmax, skipped,
                              jnp.sum(pruned.astype(jnp.int32)))
         min_d2, partials = kops.distance_min_update(
             rows, c_new, min_d2, norms=norms,
-            resident_centroids=self.resident, block_n=tile)
+            resident_centroids=self.resident, block_n=tile, cols=cols)
         if weights is not None:
             # weighted partials need the weighted sum; recompute cheaply (the
             # weights case is only used by the small k-means|| reduce).

@@ -61,6 +61,14 @@ tile multiple ONCE per job (``core.bounds.RoundCache.rows``, built by
 (`pad_rows` is then a no-op); callers with plain ``(n, d)`` points get the
 pad on each call.
 
+Small d: a ``(block_n, d)`` row tile pads d to 128 lanes (2 of 128 lanes
+are points at d=2), so the plain and gated round kernels have
+coordinate-major twins (``distance_min_update_cols*_pallas``) that stream
+``(d, block_n)`` tiles of the ``(d, n_pad)`` points (``RoundCache.cols``),
+the points on lanes and d padded only to 8 sublanes. The math, the tile
+height and every other operand are the row kernels'; ``kernels.ops`` picks
+the layout from d (`ops.streams_cols`).
+
 Raw kernels take ``interpret`` EXPLICITLY: ``kernels.ops`` is the single
 place the on-TPU/off-TPU default is chosen — calling a raw kernel without it
 is a TypeError, not a silent interpreted run on real hardware.
@@ -80,7 +88,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.bounds import seed_point_prune as _seed_point_prune
 # the cached-norm matmul-form D^2 the fused backend evaluates too (the
 # fused==pallas bitwise parity hangs off it)
-from repro.core.distance import tile_d2_min
+from repro.core.distance import tile_d2_min, tile_d2_min_cols
 
 LANES = 128                      # one lane-row per per-tile scalar block
 
@@ -188,38 +196,51 @@ def row_ids(t, block_n: int) -> jax.Array:
     return t * block_n + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
 
 
+def pad_cols(cols: jax.Array, n_pad: int) -> jax.Array:
+    """(d, r) coordinate-major points -> at least ``n_pad`` columns,
+    zero-filled: `pad_rows` of the (d, n) layout the small-d rounds stream
+    (``RoundCache.cols``, built once per call from ``points.T``)."""
+    extra = n_pad - cols.shape[-1]
+    if extra <= 0:
+        return cols
+    return jnp.pad(cols, ((0, 0), (0, extra)))
+
+
+def _point_stream(points, n_pad: int, block_n: int, tile, cols: bool):
+    """The points a round streams, and the BlockSpec of grid step i's tile
+    ``tile(i, *prefetch)``: (block_n, d) tiles of (n_pad, d) rows, or with
+    ``cols`` (d, block_n) tiles of the coordinate-major (d, n_pad) points,
+    whose d pads to 8 sublanes where a row tile pads it to 128 lanes."""
+    if cols:
+        return pad_cols(points, n_pad), pl.BlockSpec(
+            (points.shape[0], block_n), lambda i, *s: (0, tile(i, *s)))
+    return pad_rows(points, n_pad), pl.BlockSpec(
+        (block_n, points.shape[1]), lambda i, *s: (tile(i, *s), 0))
+
+
 def _round_kernel(nv_ref, pts_ref, norms_ref, cents_ref, md_ref,
-                  out_md_ref, partial_ref, *, block_n: int):
-    """Grid step i processes point rows [i*block_n, (i+1)*block_n)."""
+                  out_md_ref, partial_ref, *, block_n: int, d2_min):
+    """Grid step i processes point rows [i*block_n, (i+1)*block_n);
+    ``d2_min`` is `tile_d2_min` or, on coordinate-major tiles, its twin."""
     i = pl.program_id(0)
     md = md_ref[...].astype(jnp.float32)           # (1, block_n)
-    new_md = jnp.minimum(md, tile_d2_min(pts_ref[...], cents_ref[...],
-                                         norms_ref[...]))
+    new_md = jnp.minimum(md, d2_min(pts_ref[...], cents_ref[...],
+                                    norms_ref[...]))
     # mask padded tail rows (they must not contribute to the reduction)
     new_md = jnp.where(row_ids(i, block_n) < nv_ref[0], new_md, 0.0)
     out_md_ref[...] = new_md
     partial_ref[0] = lane_fill(jnp.sum(new_md, axis=1, keepdims=True))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_n", "resident", "interpret"))
-def distance_min_update_pallas(points: jax.Array, norms: jax.Array,
-                               centroids: jax.Array, min_d2: jax.Array, *,
-                               block_n: int, resident: bool, interpret: bool):
-    """Returns (new_min_d2 (n,), partials (grid,)). sum(partials) == sum(D^2).
-
-    ``norms`` is the cached fp32 ``||x||^2`` (n,) from the prologue.
-    ``points`` are the (n, d) points or rows already padded past n (see
-    `pad_rows`); n is ``min_d2``'s length.
-    resident=True keeps the centroid block pinned in VMEM across grid steps
-    (constant-memory analogue). resident=False re-indexes the centroid block
-    every step, modelling the global-memory variant's repeated fetch.
-    """
-    n, d = min_d2.shape[0], points.shape[1]
-    k_new = centroids.shape[0]
+def _round_call(points, norms, centroids, min_d2, *, block_n: int,
+                resident: bool, interpret: bool, cols: bool):
+    """The plain round over row tiles or, with ``cols``, coordinate-major
+    tiles (see `distance_min_update_pallas` / `_cols_pallas`)."""
+    n, (k_new, d) = min_d2.shape[0], centroids.shape
     pad = (-n) % block_n
     grid = (n + pad) // block_n
-    pts = pad_rows(points, n + pad)
+    pts, pts_spec = _point_stream(points, n + pad, block_n,
+                                  lambda i, nv: i, cols)
     nrm = as_row(norms.astype(jnp.float32), pad)
     md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
 
@@ -232,12 +253,13 @@ def distance_min_update_pallas(points: jax.Array, norms: jax.Array,
     row = pl.BlockSpec((1, block_n), lambda i, nv: (0, i))
 
     out_md, partials = pallas_call(
-        functools.partial(_round_kernel, block_n=block_n),
+        functools.partial(_round_kernel, block_n=block_n,
+                          d2_min=tile_d2_min_cols if cols else tile_d2_min),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,                  # n_valid
             grid=(grid,),
             in_specs=[
-                pl.BlockSpec((block_n, d), lambda i, nv: (i, 0)),  # points
+                pts_spec,                           # points
                 row,                                # cached ||x||^2
                 cent_spec,                          # centroids
                 row,                                # min_d2 in
@@ -256,6 +278,38 @@ def distance_min_update_pallas(points: jax.Array, norms: jax.Array,
     return out_md[0, :n], from_lanes(partials)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("block_n", "resident", "interpret"))
+def distance_min_update_pallas(points: jax.Array, norms: jax.Array,
+                               centroids: jax.Array, min_d2: jax.Array, *,
+                               block_n: int, resident: bool, interpret: bool):
+    """Returns (new_min_d2 (n,), partials (grid,)). sum(partials) == sum(D^2).
+
+    ``norms`` is the cached fp32 ``||x||^2`` (n,) from the prologue.
+    ``points`` are the (n, d) points or rows already padded past n (see
+    `pad_rows`); n is ``min_d2``'s length.
+    resident=True keeps the centroid block pinned in VMEM across grid steps
+    (constant-memory analogue). resident=False re-indexes the centroid block
+    every step, modelling the global-memory variant's repeated fetch.
+    """
+    return _round_call(points, norms, centroids, min_d2, block_n=block_n,
+                       resident=resident, interpret=interpret, cols=False)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_n", "resident", "interpret"))
+def distance_min_update_cols_pallas(cols: jax.Array, norms: jax.Array,
+                                    centroids: jax.Array, min_d2: jax.Array,
+                                    *, block_n: int, resident: bool,
+                                    interpret: bool):
+    """`distance_min_update_pallas` on coordinate-major points: ``cols`` is
+    (d, n) or already padded past n (`pad_cols`), streamed in (d, block_n)
+    tiles with the points on lanes. Same math, tile and outputs; at small d
+    a tile's bytes are the points', not 128 lanes of padding."""
+    return _round_call(cols, norms, centroids, min_d2, block_n=block_n,
+                       resident=resident, interpret=interpret, cols=True)
+
+
 # ---------------------------------------------------------------------------
 # bound-gated variant (exact tile skipping via scalar-prefetched index map)
 # ---------------------------------------------------------------------------
@@ -264,7 +318,7 @@ def distance_min_update_pallas(points: jax.Array, norms: jax.Array,
 def _round_kernel_gated(ids_ref, meta_ref, pts_ref, norms_ref, cents_ref,
                         md_ref, cdist_ref, dc_ref, margin_ref, pp_ref,
                         ptm_ref, pz_ref, out_md_ref, partial_ref,
-                        tmax_ref, pruned_ref, *, block_n: int):
+                        tmax_ref, pruned_ref, *, block_n: int, d2_min):
     """Grid step i streams tile ``ids[i]``; steps >= n_active are no-ops.
 
     ``meta`` = [n_valid, n_active]. ``pp_ref``/``ptm_ref``/``pz_ref``
@@ -274,7 +328,8 @@ def _round_kernel_gated(ids_ref, meta_ref, pts_ref, norms_ref, cents_ref,
     rows whose carried ``min_d2`` provably cannot improve (``(dc −
     center_d)² >= md`` with margin — see ``core.bounds.seed_point_prune``)
     keep it verbatim, a value-noop by construction that the ``pruned``
-    output counts (the modelled per-point FLOP saving).
+    output counts (the modelled per-point FLOP saving). ``d2_min`` is
+    `tile_d2_min` or, on coordinate-major tiles, its twin.
     """
     del pp_ref, ptm_ref, pz_ref
     i = pl.program_id(0)
@@ -287,8 +342,8 @@ def _round_kernel_gated(ids_ref, meta_ref, pts_ref, norms_ref, cents_ref,
         prune = valid & _seed_point_prune(md, cdist_ref[...],
                                           lane_scalar(dc_ref[0]),
                                           lane_scalar(margin_ref[0]))
-        upd = jnp.minimum(md, tile_d2_min(pts_ref[...], cents_ref[...],
-                                          norms_ref[...]))
+        upd = jnp.minimum(md, d2_min(pts_ref[...], cents_ref[...],
+                                     norms_ref[...]))
         new_md = jnp.where(prune, md, upd)
         new_md = jnp.where(valid, new_md, 0.0)
 
@@ -298,6 +353,66 @@ def _round_kernel_gated(ids_ref, meta_ref, pts_ref, norms_ref, cents_ref,
         tmax_ref[0] = lane_fill(jnp.max(new_md, axis=1, keepdims=True))
         pruned_ref[0] = lane_fill(jnp.sum(prune.astype(jnp.float32), axis=1,
                                           keepdims=True))
+
+
+def _gated_call(points, norms, centroids, min_d2, center_d, dc, margin,
+                prev_partials, prev_tile_max, ids, meta, *, block_n: int,
+                resident: bool, interpret: bool, cols: bool):
+    """The gated round over row tiles or, with ``cols``, coordinate-major
+    tiles (see `distance_min_update_gated_pallas` / `_cols_gated_pallas`)."""
+    n, (k_new, d) = min_d2.shape[0], centroids.shape
+    pad = (-n) % block_n
+    grid = (n + pad) // block_n
+    pts, pts_spec = _point_stream(points, n + pad, block_n,
+                                  lambda i, ids, meta: ids[i], cols)
+    nrm = as_row(norms.astype(jnp.float32), pad)
+    md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
+    cd = as_row(center_d.astype(jnp.float32), pad)
+
+    if resident:
+        cent_spec = pl.BlockSpec((k_new, d), lambda i, ids, meta: (0, 0))
+    else:
+        cent_spec = pl.BlockSpec((k_new, d), lambda i, ids, meta: (0, i * 0))
+    row = pl.BlockSpec((1, block_n), lambda i, ids, meta: (0, ids[i]))
+    one = lane_spec(lambda i, ids, meta: (ids[i], 0, 0))
+    # never-read aliased carries: ANY memory space, no per-step DMA
+    carry = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                      # ids, meta
+        grid=(grid,),
+        in_specs=[
+            pts_spec,
+            row,                                    # norms
+            cent_spec,
+            row,                                    # min_d2
+            row,                                    # center_d
+            one,                                    # dc
+            one,                                    # margin
+            carry, carry, carry,                    # prev part/tmax, zeros
+        ],
+        out_specs=[row, one, one, one],
+    )
+    out_md, partials, tile_max, pruned = pallas_call(
+        functools.partial(_round_kernel_gated, block_n=block_n,
+                          d2_min=tile_d2_min_cols if cols else tile_d2_min),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
+        ],
+        # skipped tiles reuse their prior min_d2 / partials / tile-max and
+        # report zero pruned points (the donated zeros buffer)
+        input_output_aliases={5: 0, 9: 1, 10: 2, 11: 3},
+        compiler_params=compiler_params(),
+        interpret=interpret,
+    )(ids.astype(jnp.int32), meta.astype(jnp.int32), pts, nrm, centroids,
+      md, cd, as_lanes(dc), as_lanes(margin), as_lanes(prev_partials),
+      as_lanes(prev_tile_max), jnp.zeros((grid, 1, LANES), jnp.float32))
+    return (out_md[0, :n], from_lanes(partials), from_lanes(tile_max),
+            from_lanes(pruned))
 
 
 @functools.partial(jax.jit,
@@ -324,58 +439,27 @@ def distance_min_update_gated_pallas(points: jax.Array, norms: jax.Array,
     zeros buffer). ``points`` may be the prologue's once-padded rows, which
     stream as they are; n is ``min_d2``'s length.
     """
-    n, d = min_d2.shape[0], points.shape[1]
-    k_new = centroids.shape[0]
-    pad = (-n) % block_n
-    grid = (n + pad) // block_n
-    pts = pad_rows(points, n + pad)
-    nrm = as_row(norms.astype(jnp.float32), pad)
-    md = as_row(min_d2.astype(jnp.float32), pad, jnp.inf)
-    cd = as_row(center_d.astype(jnp.float32), pad)
+    return _gated_call(points, norms, centroids, min_d2, center_d, dc, margin,
+                       prev_partials, prev_tile_max, ids, meta,
+                       block_n=block_n, resident=resident,
+                       interpret=interpret, cols=False)
 
-    if resident:
-        cent_spec = pl.BlockSpec((k_new, d), lambda i, ids, meta: (0, 0))
-    else:
-        cent_spec = pl.BlockSpec((k_new, d), lambda i, ids, meta: (0, i * 0))
-    row = pl.BlockSpec((1, block_n), lambda i, ids, meta: (0, ids[i]))
-    one = lane_spec(lambda i, ids, meta: (ids[i], 0, 0))
-    # never-read aliased carries: ANY memory space, no per-step DMA
-    carry = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                      # ids, meta
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, ids, meta: (ids[i], 0)),
-            row,                                    # norms
-            cent_spec,
-            row,                                    # min_d2
-            row,                                    # center_d
-            one,                                    # dc
-            one,                                    # margin
-            carry, carry, carry,                    # prev part/tmax, zeros
-        ],
-        out_specs=[row, one, one, one],
-    )
-    out_md, partials, tile_max, pruned = pallas_call(
-        functools.partial(_round_kernel_gated, block_n=block_n),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
-            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
-        ],
-        # skipped tiles reuse their prior min_d2 / partials / tile-max and
-        # report zero pruned points (the donated zeros buffer)
-        input_output_aliases={5: 0, 9: 1, 10: 2, 11: 3},
-        compiler_params=compiler_params(),
-        interpret=interpret,
-    )(ids.astype(jnp.int32), meta.astype(jnp.int32), pts, nrm, centroids,
-      md, cd, as_lanes(dc), as_lanes(margin), as_lanes(prev_partials),
-      as_lanes(prev_tile_max), jnp.zeros((grid, 1, LANES), jnp.float32))
-    return (out_md[0, :n], from_lanes(partials), from_lanes(tile_max),
-            from_lanes(pruned))
+@functools.partial(jax.jit,
+                   static_argnames=("block_n", "resident", "interpret"))
+def distance_min_update_cols_gated_pallas(
+        cols: jax.Array, norms: jax.Array, centroids: jax.Array,
+        min_d2: jax.Array, center_d: jax.Array, dc: jax.Array,
+        margin: jax.Array, prev_partials: jax.Array,
+        prev_tile_max: jax.Array, ids: jax.Array, meta: jax.Array, *,
+        block_n: int, resident: bool, interpret: bool):
+    """`distance_min_update_gated_pallas` on coordinate-major points
+    (``cols`` (d, n) or padded past n, see `pad_cols`): active tile ids[i]
+    streams as a (d, block_n) block. Same gate, prune, aliases and outputs."""
+    return _gated_call(cols, norms, centroids, min_d2, center_d, dc, margin,
+                       prev_partials, prev_tile_max, ids, meta,
+                       block_n=block_n, resident=resident,
+                       interpret=interpret, cols=True)
 
 
 # ---------------------------------------------------------------------------

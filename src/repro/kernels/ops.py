@@ -27,6 +27,12 @@ per-point vectors, so ``points`` may be the engine prologue's once-padded
 rows (``core.bounds.RoundCache.rows``, built by `pad_rows_blocked`; the cached
 ``norms`` then come with them), which stream as they are. Plain ``(n, d)``
 points are padded inside the kernel wrapper on each call.
+
+Small d: given ``cols=``, the same points coordinate-major (``(d, n)`` or
+the prologue's once-padded ``RoundCache.cols``), the seeding-round wrappers
+launch the kernels' coordinate-major twins on them; the batch-grid kernels
+under ``vmap`` keep streaming the rows. `streams_cols` is the one rule for
+when the prologue builds them.
 """
 from __future__ import annotations
 
@@ -38,12 +44,13 @@ from jax.custom_batching import custom_vmap
 
 from repro.kernels.kmeans_distance import (
     LANES, distance_min_update_batched_pallas,
+    distance_min_update_cols_gated_pallas, distance_min_update_cols_pallas,
     distance_min_update_gated_pallas,
     distance_min_update_gated_batched_pallas, distance_min_update_pallas,
     row_min_d2_pallas, seed_prologue_pallas, tile_cap_pallas)
 from repro.kernels.kmeans_distance import (  # noqa: F401  (re-exported:
-    pad_rows, pad_rows_blocked)  # the engine's prologue pads the points
-#   once per job with the blocked copy)
+    pad_cols, pad_rows, pad_rows_blocked)  # the engine's prologue pads the
+#   points once per job: rows with the blocked copy, small-d columns)
 from repro.core.bounds import point_norms  # noqa: F401  (re-exported: the
 #   cached-norm input the kernels stream; wrappers compute it on the fly
 #   when the caller has no prologue cache)
@@ -64,6 +71,14 @@ _VMEM_HEADROOM = 2
 # the winner mask / one-hot, and the masked selects built from them
 _D2_LIVE = 4
 _SUBLANES = 8                     # f32 VMEM tiles are (8, LANES)
+
+
+def streams_cols(d: int) -> bool:
+    """Whether the seeding rounds stream the points coordinate-major, (d, n)
+    with the points on lanes: where a (block_n, d) row tile, d padded to
+    LANES, takes at least four times the bytes of a (d, block_n) tile, d
+    padded to _SUBLANES (d <= 32). Larger d keeps the row stream."""
+    return _pad_to(d, LANES) >= 4 * _pad_to(d, _SUBLANES)
 
 
 def vmem_limit_bytes() -> int:
@@ -234,15 +249,18 @@ def distance_min_update(points: jax.Array, centroids: jax.Array,
                         min_d2: jax.Array, *, norms: jax.Array | None = None,
                         resident_centroids: bool = True,
                         block_n: int | None = None,
-                        interpret: bool | None = None):
+                        interpret: bool | None = None,
+                        cols: jax.Array | None = None):
     """One k-means++ seeding round: fused D^2 min-update + per-tile partials.
 
     Returns (new_min_d2 (n,), partials (n_tiles,)) with the tile height
     `choose_block_n(n, d, k)` — the same tile the two-level `tiled` sampler
     draws from. n is ``min_d2``'s length (``points`` may be once-padded
-    rows when ``norms`` is given). Under `jax.vmap` this dispatches to the
-    batch-grid kernel (`distance_min_update_batched`), not a per-problem
-    loop."""
+    rows when ``norms`` is given). ``cols``, the same points
+    coordinate-major ((d, n) or padded past n), run the coordinate-major
+    kernel instead. Under `jax.vmap` this dispatches to the batch-grid
+    kernel (`distance_min_update_batched`, on ``points``), not a
+    per-problem loop."""
     _check_forced()
     n, d = min_d2.shape[0], points.shape[1]
     k = centroids.shape[0]
@@ -252,16 +270,18 @@ def distance_min_update(points: jax.Array, centroids: jax.Array,
     if interpret is None:
         interpret = default_interpret()
     centroids, norms = _align(points, centroids, norms)
+    kw = dict(block_n=block_n, resident=resident_centroids,
+              interpret=interpret)
 
     @custom_vmap
-    def call(pts, cents, md, nrm):
-        return distance_min_update_pallas(pts, nrm, cents, md,
-                                          block_n=block_n,
-                                          resident=resident_centroids,
-                                          interpret=interpret)
+    def call(pts, cents, md, nrm, *cols_):
+        if cols_:
+            return distance_min_update_cols_pallas(cols_[0], nrm, cents, md,
+                                                   **kw)
+        return distance_min_update_pallas(pts, nrm, cents, md, **kw)
 
     @call.def_vmap
-    def _rule(axis_size, in_batched, pts, cents, md, nrm):
+    def _rule(axis_size, in_batched, pts, cents, md, nrm, *cols_):
         pts = _ensure_batched(pts, in_batched[0], axis_size)
         cents = _ensure_batched(cents, in_batched[1], axis_size)
         md = _ensure_batched(md, in_batched[2], axis_size)
@@ -272,7 +292,8 @@ def distance_min_update(points: jax.Array, centroids: jax.Array,
                                           interpret=interpret)
         return out, (True, True)
 
-    return call(points, centroids, min_d2, norms)
+    return call(points, centroids, min_d2, norms,
+                *(() if cols is None else (cols,)))
 
 
 def distance_min_update_batched(points: jax.Array, centroids: jax.Array,
@@ -302,7 +323,8 @@ def distance_min_update_gated(points: jax.Array, centroids: jax.Array,
                               prev_tile_max: jax.Array, active: jax.Array, *,
                               block_n: int,
                               resident_centroids: bool = True,
-                              interpret: bool | None = None):
+                              interpret: bool | None = None,
+                              cols: jax.Array | None = None):
     """Bound-gated seeding round (two-level exact pruning).
 
     ``active``/``dc``/``margin`` come from `core.bounds.seed_gate` and
@@ -315,8 +337,9 @@ def distance_min_update_gated(points: jax.Array, centroids: jax.Array,
     skipped). ``block_n`` is required: it must match the tile height of the
     carried bound state. ``points`` may be the prologue's once-padded rows
     (n is ``min_d2``'s length); the grid is ``ceil(n / block_n)`` either
-    way. Under `jax.vmap` this dispatches to the gated batch-grid kernel
-    with per-problem compaction."""
+    way. ``cols`` run the coordinate-major kernel, as in
+    `distance_min_update`. Under `jax.vmap` this dispatches to the gated
+    batch-grid kernel (on ``points``) with per-problem compaction."""
     from repro.core import bounds as bnd
 
     _check_forced()
@@ -330,17 +353,19 @@ def distance_min_update_gated(points: jax.Array, centroids: jax.Array,
     skipped = (grid - n_active).astype(jnp.int32)
 
     @custom_vmap
-    def call(pts, cents, md, nrm, cd, dc_, mg, pp, ptm, ids_, nact):
+    def call(pts, cents, md, nrm, cd, dc_, mg, pp, ptm, ids_, nact, *cols_):
         meta = jnp.stack([jnp.full((), n, jnp.int32), nact.astype(jnp.int32)])
-        return distance_min_update_gated_pallas(
-            pts, nrm, cents, md, cd, dc_, mg, pp, ptm, ids_, meta,
-            block_n=block_n, resident=resident_centroids,
-            interpret=interpret)
+        kernel = distance_min_update_gated_pallas
+        if cols_:
+            pts, kernel = cols_[0], distance_min_update_cols_gated_pallas
+        return kernel(pts, nrm, cents, md, cd, dc_, mg, pp, ptm, ids_, meta,
+                      block_n=block_n, resident=resident_centroids,
+                      interpret=interpret)
 
     @call.def_vmap
     def _rule(axis_size, in_batched, *args):
         args = [_ensure_batched(a, b, axis_size)
-                for a, b in zip(args, in_batched)]
+                for a, b in zip(args[:11], in_batched)]
         pts, cents, md, nrm, cd, dc_, mg, pp, ptm, ids_, nact = args
         out = distance_min_update_gated_batched_pallas(
             pts, nrm, cents, md, cd, dc_, mg, pp, ptm, ids_, nact,
@@ -349,7 +374,8 @@ def distance_min_update_gated(points: jax.Array, centroids: jax.Array,
 
     new_md, partials, tile_max, pruned = call(
         points, centroids, min_d2, norms, center_d.astype(jnp.float32), dc,
-        margin, prev_partials, prev_tile_max, ids, n_active)
+        margin, prev_partials, prev_tile_max, ids, n_active,
+        *(() if cols is None else (cols,)))
     return new_md, partials, tile_max, pruned, skipped
 
 

@@ -1,7 +1,8 @@
 """Pallas kernel tests: interpret-mode execution swept over shapes/dtypes,
 assert_allclose against the pure-jnp oracles in kernels/ref.py, plus
 BITWISE pins between the plain and bound-gated kernel paths (tile skipping
-is exact) and between single and batch-grid launches."""
+is exact) and between single and batch-grid launches, and the small-d
+seeding rounds' coordinate-major twins against the row kernels."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -400,6 +401,154 @@ def test_gated_batched_matches_single():
         for o, w in zip(out[:4], s[:4]):
             np.testing.assert_array_equal(np.asarray(o[b]), np.asarray(w))
         assert int(out[4][b]) == int(s[4])
+
+
+# ---------------------------------------------------------------------------
+# coordinate-major (small d) round kernels: (d, block_n) tiles of (d, n_pad)
+# ---------------------------------------------------------------------------
+
+COLS_DIMS = [1, 2, 3, 8, 32]
+
+
+def _layouts_agree(got, want, d):
+    """The two layouts' outputs: bitwise at d <= 3; past that the CPU's dot
+    sums the d products of a (d, block_n) tile in another order than those
+    of a (block_n, d) tile, so they agree to fp32 rounding."""
+    if d <= 3:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("d", COLS_DIMS)
+def test_cols_round_matches_rows(d):
+    """The plain coordinate-major round gives the row round's min_d2 and
+    partials, n not a tile multiple; it pads unpadded (d, n) points
+    itself."""
+    n, bn = 1000, 128
+    pts, cents, md = _mk(n, d, 2, jnp.float32, seed=d)
+    nrm = ops.point_norms(pts)
+    cols = ops.pad_cols(pts.T, -(-n // bn) * bn)
+    got = ops.distance_min_update(pts, cents, md, norms=nrm, block_n=bn,
+                                  cols=cols)
+    want = ops.distance_min_update(pts, cents, md, norms=nrm, block_n=bn)
+    for g, w in zip(got, want):
+        _layouts_agree(g, w, d)
+    unpadded = ops.distance_min_update(pts, cents, md, norms=nrm,
+                                       block_n=bn, cols=pts.T)
+    for g, w in zip(unpadded, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("d", COLS_DIMS)
+def test_cols_gated_round_matches_rows(d):
+    """The gated coordinate-major round against the gated row round on the
+    same inputs: a round with every tile active, then one that skips tiles
+    and prunes points. min_d2, partials and tile max agree as the layouts
+    do; pruned and skipped are equal. The coordinate-major gated round is
+    bitwise its own plain round, as the row kernels are."""
+    n, bn = 1000, 128
+    pts, md0, nrm, grid, pp0, tm0 = _gated_setup(n=n, d=d, block_n=bn,
+                                                 seed=d)
+    cols = ops.pad_cols(pts.T, grid * bn)
+    cache = bounds.RoundCache(nrm, *seed_prologue_pallas(
+        pts, block_n=bn, interpret=True)[1:])
+
+    def both(c, md, pp, tm):
+        a, dc, mg = bounds.seed_gate(c, cache, tm)
+        args = (pts, c, md, nrm, cache.center_d, dc, mg, pp, tm, a)
+        return (ops.distance_min_update_gated(*args, block_n=bn, cols=cols),
+                ops.distance_min_update_gated(*args, block_n=bn), a)
+
+    got, want, _ = both(pts[3:4], md0, pp0, tm0)
+    for g, w in zip(got[:3], want[:3]):
+        _layouts_agree(g, w, d)
+    assert int(got[4]) == int(want[4]) == 0
+    # round 2 from the row round's state: a far centroid skips tiles
+    md1, p1, tm1 = want[:3]
+    c2 = jnp.full((1, d), 50.0)
+    got, want, a2 = both(c2, md1, p1, tm1)
+    assert int(jnp.sum(a2)) < grid, "probe must actually skip tiles"
+    assert int(got[4]) == int(want[4]) > 0
+    assert float(jnp.sum(got[3])) > 0
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
+    for g, w in zip(got[:3], want[:3]):
+        _layouts_agree(g, w, d)
+    plain_md, plain_p = ops.distance_min_update(pts, c2, md1, norms=nrm,
+                                                block_n=bn, cols=cols)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(plain_md))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(plain_p))
+    np.testing.assert_array_equal(
+        np.asarray(got[2]), np.asarray(bounds.tile_reduce_max(plain_md, bn)))
+
+
+def test_cols_under_vmap_stream_rows():
+    """Under vmap the wrappers given ``cols`` run the row batch-grid
+    kernels on the rows: bitwise the wrappers without ``cols`` under vmap,
+    plain and gated."""
+    B, n, d, bn = 2, 300, 2, 128
+    keys = jax.random.split(jax.random.PRNGKey(21), 3)
+    pts = jax.random.normal(keys[0], (B, n, d))
+    cols = jnp.swapaxes(pts, 1, 2)
+    cents = jax.random.normal(keys[1], (B, 1, d))
+    md = jnp.abs(jax.random.normal(keys[2], (B, n))) * 2
+    nrm = jax.vmap(ops.point_norms)(pts)
+    grid = -(-n // bn)
+    active = jnp.arange(grid)[None, :] % 2 == jnp.arange(B)[:, None]
+
+    def plain(p, c, m, nr, a, cl=None):
+        return ops.distance_min_update(p, c, m, norms=nr, block_n=bn, cols=cl)
+
+    def gated(p, c, m, nr, a, cl=None):
+        return ops.distance_min_update_gated(
+            p, c, m, nr, jnp.zeros((n,)), jnp.zeros((grid,)),
+            jnp.zeros((grid,)), jnp.zeros((grid,)),
+            jnp.full((grid,), jnp.inf), a, block_n=bn, cols=cl)
+
+    for fn in (plain, gated):
+        got = jax.vmap(fn)(pts, cents, md, nrm, active, cols)
+        want = jax.vmap(fn)(pts, cents, md, nrm, active)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("d", [2, 32, 33, 128, 784])
+def test_prologue_builds_cols_at_small_d_only(d):
+    """The Pallas prologue builds ``RoundCache.cols`` — the padded rows,
+    transposed — where `ops.streams_cols` says so (d <= 32), and leaves it
+    None at larger d, with and without the bound balls."""
+    from repro.core.engine import PallasBackend
+    pts = _mk(300, d, 1, jnp.float32, seed=d)[0]
+    assert ops.streams_cols(d) == (d <= 32)
+    for with_bounds in (True, False):
+        cache = PallasBackend().prologue(pts, with_bounds=with_bounds)
+        if d > 32:
+            assert cache.cols is None
+            continue
+        assert cache.cols.shape == (d, cache.rows.shape[0])
+        np.testing.assert_array_equal(np.asarray(cache.cols),
+                                      np.asarray(cache.rows.T))
+
+
+@pytest.mark.parametrize("d", [2, 8])
+def test_engine_seeding_on_cols_matches_rows(d):
+    """The Pallas seeding loop on the coordinate-major copy picks the seeds
+    the row stream picks, with the same skip and prune counts."""
+    from repro.core.engine import PallasBackend, seed_points
+    from repro.data.synthetic import blobs
+    pts = jnp.asarray(blobs(700, d, 6, seed=d)[0])
+    be = PallasBackend()
+    cache = be.prologue(pts)
+    assert cache.cols is not None
+    key = jax.random.PRNGKey(3)
+    got = seed_points(key, pts, 8, None, be, cache=cache)
+    want = seed_points(key, pts, 8, None, be, cache=cache._replace(cols=None))
+    for name in ("indices", "skipped", "pruned"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(want, name)),
+                                      err_msg=name)
+    _layouts_agree(got.min_d2, want.min_d2, d)
 
 
 # ---------------------------------------------------------------------------

@@ -9,9 +9,12 @@ Widths: the paper's job (n=4,000,000, d=2, k=50), the IVF build's Lloyd
 kernels (n=1,048,576, d=128, k=4096), one chip's shard of the four-chip
 MNIST8m job (n=2,025,000, d=784, k=256; 784 is no multiple of 128 lanes)
 and the IVF scan at Q=1,000 over that index (k=10, the build's 128-row
-tiles). Tile heights come from the same pickers the engine and the index
-builder use."""
+tiles). At the paper's d=2 the seeding rounds stream the points
+coordinate-major, (2, n_pad), through the rounds' coordinate-major twins.
+Tile heights come from the same pickers the engine and the index builder
+use."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +25,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import bounds
 from repro.kernels import ops
 from repro.kernels.ivf_scan import ivf_scan_pallas
-from repro.kernels.kmeans_distance import (distance_min_update_gated_pallas,
-                                           distance_min_update_pallas,
-                                           seed_prologue_pallas)
+from repro.kernels.kmeans_distance import (
+    distance_min_update_cols_gated_pallas, distance_min_update_cols_pallas,
+    distance_min_update_gated_pallas, distance_min_update_pallas,
+    seed_prologue_pallas)
 from repro.kernels.lloyd_assign import (lloyd_assign_gated_pallas,
                                         lloyd_assign_tiled_pallas)
 
@@ -120,6 +124,64 @@ def test_round_gated_compiles(chip, width):
               ((n,), F32), ((n,), F32), ((t,), F32), ((t,), F32),
               ((t,), F32), ((t,), F32), ((t,), I32), ((2,), I32)],
              width=width, block_n=bn, resident=True)
+
+
+def _round_kernels(text):
+    """{name: stream operand} of every seeding-round kernel (a custom call
+    named ``distance_min_update*``) in compiled HLO ``text``."""
+    found = {}
+    for line in text.splitlines():
+        m = re.search(r"%(distance_min_update\w*)\.\d+ = .*"
+                      r"operand_layout_constraints=\{(.*)", line)
+        if m:   # operand 0 is the valid count, or the gated tile map
+            operands = re.findall(r"\w+\[[\d,]*\]\{[\d,]*\}", m.group(2))
+            found[m.group(1)] = operands[2 if "gated" in m.group(1) else 1]
+    return found
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+def test_round_cols_compiles(chip, gated):
+    """The coordinate-major round kernels at the paper's width stream the
+    (2, n_pad) points in (2, block_n) tiles: the stream operand is
+    ``f32[2, n_pad]`` and the round needs no temporary near the 2.05 GB a
+    lane-padded (n_pad, 2) copy takes (it needs ~16 MB)."""
+    n, d, k = PAPER
+    bn = _tile(n, d, k)
+    t = -(-n // bn)
+    shapes = [((d, t * bn), F32), ((n,), F32), ((1, d), F32), ((n,), F32)]
+    fn = distance_min_update_cols_pallas
+    if gated:
+        shapes += [((n,), F32), ((t,), F32), ((t,), F32), ((t,), F32),
+                   ((t,), F32), ((t,), I32), ((2,), I32)]
+        fn = distance_min_update_cols_gated_pallas
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    compiled = fn.lower(*args, block_n=bn, resident=True,
+                        interpret=False).compile()
+    assert _round_kernels(compiled.as_text()) == {
+        fn.__name__: f"f32[{d},{t * bn}]{{1,0}}"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_paper_job_seeds_on_cols(chip, monkeypatch):
+    """The paper's whole kmeans job (n=4,000,000, d=2, k=50) on one chip:
+    its seeding rounds run the coordinate-major gated kernel on the
+    prologue's (2, n_pad) copy, and no round kernel streams the
+    lane-padded (n_pad, 2) rows."""
+    from repro.core import engine
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    n, d, k = PAPER
+    t = -(-n // _tile(n, d, k))
+    pts = jax.ShapeDtypeStruct((n, d), F32, sharding=chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
+    compiled = engine._kmeans_jit.lower(
+        key, pts, None, k=k, backend=engine.make_backend("pallas"),
+        sampler="cdf", max_iters=25, tol=1e-6, empty="keep",
+        precision="fp32", bound_gate=True, refresh_block=8,
+        guard=False).compile()
+    assert _round_kernels(compiled.as_text()) == {
+        "distance_min_update_cols_gated_pallas":
+            f"f32[{d},{t * _tile(n, d, k)}]{{1,0}}"}
 
 
 @pytest.mark.parametrize("width", **WIDTHS)
